@@ -82,6 +82,13 @@ def jit_prefill(cfg: ModelConfig, max_len: int):
 
 
 @functools.lru_cache(maxsize=64)
+def jit_decode(cfg: ModelConfig):
+    def f(params, caches, tokens):
+        return model.decode_step(params, cfg, caches, tokens)
+    return jax.jit(f)
+
+
+@functools.lru_cache(maxsize=64)
 def jit_verify_accept(cfg: ModelConfig, ssv: SSVConfig, greedy: bool,
                       temperature: float):
     """Fused verify → tree-accept → commit step for the target model.
@@ -1428,7 +1435,7 @@ def autoregressive_decode(params, cfg: ModelConfig, prompt_tokens: np.ndarray,
     toks = jnp.asarray(prompt_tokens, jnp.int32)[None]
     # prefill all but the last prompt token; the first decode step processes it
     _, caches = jit_prefill(cfg, max_context)(params, toks[:, :-1])
-    step = jax.jit(lambda p, c, t: model.decode_step(p, cfg, c, t))
+    step = jit_decode(cfg)
     rng = np.random.default_rng(seed)
     cur = jnp.asarray([[int(prompt_tokens[-1])]], jnp.int32)
     committed = len(prompt_tokens) - 1   # host-side length mirror, no sync

@@ -19,9 +19,9 @@ def make_kernel(*, R: int, Gq: int, Dh: int, TS: int, ST: int, Tp: int,
                 window: int):
     TOTAL = ST + 1
 
-    def kernel(s_pos, s_scalar, q_ref, k_ref, v_ref, kd_ref, vd_ref, dmask_ref,
-               o_ref, acc_ref, l_ref, m_ref):
-        b, h, w = (pl.program_id(i) for i in range(3))
+    def kernel(s_scalar, pos_ref, q_ref, k_ref, v_ref, kd_ref, vd_ref,
+               dmask_ref, o_ref, acc_ref, l_ref, m_ref):
+        w = pl.program_id(2)
 
         @pl.when(w == 0)
         def _init():
@@ -30,37 +30,39 @@ def make_kernel(*, R: int, Gq: int, Dh: int, TS: int, ST: int, Tp: int,
             m_ref[...] = jnp.full_like(m_ref, NEG)
 
         q = q_ref[0, 0].astype(jnp.float32)                 # (R, Dh)
-        pos_r = jnp.repeat(s_pos[b], Gq, total_repeat_length=R)
+        pos_r = pos_ref[0]                                  # (R, 1)
         prefix_len = s_scalar[0]
 
-        def update(logits, mask, v):
+        def update(k, mask, v):
+            logits = jax.lax.dot_general(
+                q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
             lm = jnp.where(mask, logits, NEG)
-            m_new = jnp.maximum(m_ref[0], lm.max(-1))
-            alpha = jnp.exp(m_ref[0] - m_new)
-            p = jnp.exp(lm - m_new[:, None]) * mask
-            l_ref[0] = l_ref[0] * alpha + p.sum(-1)
-            acc_ref[0] = acc_ref[0] * alpha[:, None] + p @ v.astype(jnp.float32)
-            m_ref[0] = m_new
+            m_new = jnp.maximum(m_ref[...], lm.max(-1, keepdims=True))
+            alpha = jnp.exp(m_ref[...] - m_new)
+            p = jnp.where(mask, jnp.exp(lm - m_new), 0.0)
+            l_ref[...] = l_ref[...] * alpha + p.sum(-1, keepdims=True)
+            acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+                p, v.astype(jnp.float32), preferred_element_type=jnp.float32)
+            m_ref[...] = m_new
 
         @pl.when(w < ST)
         def _cache():
             t = jnp.minimum(w, ST - 1)
-            kpos = t * TS + jnp.arange(TS)
-            mask = (kpos[None, :] < prefix_len) & (kpos[None, :] <= pos_r[:, None])
+            kpos = t * TS + jax.lax.broadcasted_iota(jnp.int32, (1, TS), 1)
+            mask = (kpos < prefix_len) & (kpos <= pos_r)
             if window > 0:
-                mask &= kpos[None, :] > pos_r[:, None] - window
-            update(q @ k_ref[0, :, 0].astype(jnp.float32).T, mask, v_ref[0, :, 0])
+                mask &= kpos > pos_r - window
+            update(k_ref[0, 0], mask, v_ref[0, 0])
 
         @pl.when(w == ST)
         def _draft():
-            mask = dmask_ref[0] > 0                          # (R, Tp)
-            update(q @ kd_ref[0, :, 0].astype(jnp.float32).T, mask, vd_ref[0, :, 0])
+            update(kd_ref[0, 0], dmask_ref[0] > 0, vd_ref[0, 0])   # (R, Tp)
 
         @pl.when(w == TOTAL - 1)
         def _fin():
-            l = l_ref[0]
-            o_ref[0, 0] = jnp.where(l[:, None] > 0,
-                                    acc_ref[0] / jnp.maximum(l, 1e-30)[:, None],
+            l = l_ref[...]
+            o_ref[0, 0] = jnp.where(l > 0, acc_ref[...] / jnp.maximum(l, 1e-30),
                                     0.0).astype(o_ref.dtype)
 
     return kernel, TOTAL
@@ -68,7 +70,11 @@ def make_kernel(*, R: int, Gq: int, Dh: int, TS: int, ST: int, Tp: int,
 
 def build_flash_verify(*, B: int, Hkv: int, R: int, Gq: int, Dh: int, Sp: int,
                        Tp: int, TS: int = 128, window: int = 0,
-                       out_dtype=jnp.float32, interpret: bool = True):
+                       out_dtype=jnp.float32, interpret: bool):
+    """Returns fn(s_scalar, pos_rows (B, R, 1), q (B, Hkv, R, Dh),
+    k, v (B, Hkv, Sp, Dh), k_draft, v_draft (B, Hkv, Tp, Dh),
+    dmask (B, R, Tp)) -> (B, Hkv, R, Dh). K/V are head-major so each block
+    is a (tokens, Dh) tile."""
     TS = min(TS, Sp)
     ST = max(1, Sp // TS)
     kernel, TOTAL = make_kernel(R=R, Gq=Gq, Dh=Dh, TS=TS, ST=ST, Tp=Tp,
@@ -76,26 +82,27 @@ def build_flash_verify(*, B: int, Hkv: int, R: int, Gq: int, Dh: int, Sp: int,
     grid = (B, Hkv, TOTAL)
 
     def cache_tile(b, h, w, *s):
-        return (b, jnp.minimum(w, ST - 1), h, 0)
+        return (b, h, jnp.minimum(w, ST - 1), 0)
 
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=1,
             grid=grid,
             in_specs=[
+                pl.BlockSpec((1, R, 1), lambda b, h, w, *s: (b, 0, 0)),         # pos
                 pl.BlockSpec((1, 1, R, Dh), lambda b, h, w, *s: (b, h, 0, 0)),  # q
-                pl.BlockSpec((1, TS, 1, Dh), cache_tile),                        # k
-                pl.BlockSpec((1, TS, 1, Dh), cache_tile),                        # v
-                pl.BlockSpec((1, Tp, 1, Dh), lambda b, h, w, *s: (b, 0, h, 0)),  # k_draft
-                pl.BlockSpec((1, Tp, 1, Dh), lambda b, h, w, *s: (b, 0, h, 0)),  # v_draft
+                pl.BlockSpec((1, 1, TS, Dh), cache_tile),                        # k
+                pl.BlockSpec((1, 1, TS, Dh), cache_tile),                        # v
+                pl.BlockSpec((1, 1, Tp, Dh), lambda b, h, w, *s: (b, h, 0, 0)),  # k_draft
+                pl.BlockSpec((1, 1, Tp, Dh), lambda b, h, w, *s: (b, h, 0, 0)),  # v_draft
                 pl.BlockSpec((1, R, Tp), lambda b, h, w, *s: (b, 0, 0)),         # dmask
             ],
             out_specs=pl.BlockSpec((1, 1, R, Dh), lambda b, h, w, *s: (b, h, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((1, R, Dh), jnp.float32),
-                pltpu.VMEM((1, R), jnp.float32),
-                pltpu.VMEM((1, R), jnp.float32),
+                pltpu.VMEM((R, Dh), jnp.float32),
+                pltpu.VMEM((R, 1), jnp.float32),
+                pltpu.VMEM((R, 1), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hkv, R, Dh), out_dtype),

@@ -29,28 +29,33 @@ semantics on TPU.
 """
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG = -1e30
 
 
+def _qk(q, k):
+    """(R, Dh) x (K, Dh) -> (R, K) f32 logits (contract the head dim)."""
+    return jax.lax.dot_general(q, k.astype(jnp.float32),
+                               (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
 def _update(acc_ref, l_ref, m_ref, br: int, logits, mask, v_tile):
     """Online-softmax accumulation for one branch slot ``br``.
-    logits: (R, K) f32; mask: (R, K) bool; v_tile: (K, Dh)."""
+    logits: (R, K) f32; mask: (R, K) bool; v_tile: (K, Dh). The running max
+    and sum are (R, 1) columns, so every value stays 2-D."""
     lm = jnp.where(mask, logits, NEG)
-    m_old = m_ref[br]                                    # (R,)
-    m_new = jnp.maximum(m_old, lm.max(axis=-1))
+    m_old = m_ref[br]                                    # (R, 1)
+    m_new = jnp.maximum(m_old, lm.max(axis=-1, keepdims=True))
     alpha = jnp.exp(m_old - m_new)
-    p = jnp.exp(lm - m_new[:, None]) * mask
-    l_ref[br] = l_ref[br] * alpha + p.sum(axis=-1)
-    acc_ref[br] = acc_ref[br] * alpha[:, None] + p @ v_tile.astype(jnp.float32)
+    p = jnp.where(mask, jnp.exp(lm - m_new), 0.0)
+    l_ref[br] = l_ref[br] * alpha + p.sum(axis=-1, keepdims=True)
+    acc_ref[br] = acc_ref[br] * alpha + jnp.dot(
+        p, v_tile.astype(jnp.float32), preferred_element_type=jnp.float32)
     m_ref[br] = m_new
 
 
@@ -58,21 +63,21 @@ def make_kernel(*, C: int, Gq: int, Dh: int, M: int, TC: int, NCB_T: int,
                 TW: int, WT: int, Tp: int, sel_block: int, cmp_block: int,
                 cmp_stride: int, window: int, include_cmp: bool,
                 include_sel: bool, include_win: bool, combine: bool,
-                has_cmp_in: bool, paged: bool = False):
+                has_cmp_in: bool, G: int, Hkv: int, paged: bool = False):
     R = C * Gq
     CMP_STEPS = NCB_T if include_cmp else 0
     SEL_STEPS = M if include_sel else 0
     WIN_STEPS = (WT + 1) if include_win else 0     # +1 = draft tile step
     TOTAL = max(CMP_STEPS + SEL_STEPS + WIN_STEPS, 1)
 
-    def kernel(s_merged, s_mvalid, s_own, s_pos, s_scalar, *tail):
+    def kernel(s_merged, s_mvalid, s_own, s_scalar, *tail):
         # paged store: the scalar-prefetched page table drives the BlockSpec
         # index_map (logical block -> physical pool block); the kernel body
         # itself stays position-based on LOGICAL indices, so the masks below
         # are backend-oblivious.
         if paged:
             _s_pages, *tail = tail
-        (q_ref, kcmp_ref, vcmp_ref, kblk_ref, vblk_ref, kwin_ref,
+        (pos_ref, q_ref, kcmp_ref, vcmp_ref, kblk_ref, vblk_ref, kwin_ref,
          vwin_ref, kdr_ref, vdr_ref, gates_ref, dmask_ref, *rest) = tail
         if has_cmp_in:
             ocmp_ref, o_ref, acc_ref, l_ref, m_ref = rest
@@ -87,8 +92,7 @@ def make_kernel(*, C: int, Gq: int, Dh: int, M: int, TC: int, NCB_T: int,
             m_ref[...] = jnp.full_like(m_ref, NEG)
 
         q = q_ref[0, 0, 0].astype(jnp.float32)                     # (R, Dh)
-        pos_c = s_pos[b, g]                                         # (C,) SMEM
-        pos_r = jnp.repeat(pos_c, Gq, total_repeat_length=R)        # (R,)
+        pos_r = pos_ref[0, 0]                                       # (R, 1)
         prefix_len = s_scalar[0]
         ncb_valid = s_scalar[1]
         win_start = s_scalar[2]
@@ -97,50 +101,60 @@ def make_kernel(*, C: int, Gq: int, Dh: int, M: int, TC: int, NCB_T: int,
             @pl.when(w < CMP_STEPS)
             def _cmp():
                 t = jnp.minimum(w, NCB_T - 1)
-                ids = t * TC + jnp.arange(TC)
+                ids = t * TC + jax.lax.broadcasted_iota(jnp.int32, (1, TC), 1)
                 ends = ids * cmp_stride + cmp_block - 1
-                mask = (ends[None, :] <= pos_r[:, None]) & (ids[None, :] < ncb_valid)
-                kt = kcmp_ref[0, :, 0].astype(jnp.float32)          # (TC, Dh)
-                _update(acc_ref, l_ref, m_ref, 0, q @ kt.T, mask, vcmp_ref[0, :, 0])
+                mask = (ends <= pos_r) & (ids < ncb_valid)
+                _update(acc_ref, l_ref, m_ref, 0, _qk(q, kcmp_ref[0, 0]), mask,
+                        vcmp_ref[0, 0])
 
         if include_sel:
             @pl.when((w >= CMP_STEPS) & (w < CMP_STEPS + SEL_STEPS))
             def _sel():
                 m = jnp.clip(w - CMP_STEPS, 0, M - 1)
-                blk = s_merged[b, g, h, m]
-                tok = blk * sel_block + jnp.arange(sel_block)
-                ownrow = s_own[b, g, h, :, m]                       # (C,) int32
-                own_r = jnp.repeat(ownrow, Gq, total_repeat_length=R) > 0
-                mask = (tok[None, :] < prefix_len) & (tok[None, :] <= pos_r[:, None]) \
-                    & (s_mvalid[b, g, h, m] > 0) & own_r[:, None]
-                kt = kblk_ref[0, 0, :, 0].astype(jnp.float32)       # (l', Dh)
-                _update(acc_ref, l_ref, m_ref, 1, q @ kt.T, mask, vblk_ref[0, 0, :, 0])
+                slot = ((b * G + g) * Hkv + h) * M + m
+                blk = s_merged[slot]
+                # an invalid merged slot admits no token: fold its validity
+                # into the scalar prefix bound instead of a vector mask
+                limit = jnp.where(s_mvalid[slot] > 0, prefix_len, 0)
+                tok = blk * sel_block + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, sel_block), 1)
+                # ownership of this slot per query row: rows
+                # [c*Gq, (c+1)*Gq) belong to the group's c-th query
+                row = jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0)
+                own_r = jnp.zeros((R, 1), jnp.int32)
+                for c in range(C):
+                    own_c = s_own[(((b * G + g) * Hkv + h) * C + c) * M + m]
+                    own_r = jnp.where((row >= c * Gq) & (row < (c + 1) * Gq),
+                                      own_c, own_r)
+                mask = (tok < limit) & (tok <= pos_r) & (own_r > 0)
+                _update(acc_ref, l_ref, m_ref, 1, _qk(q, kblk_ref[0, 0, 0]),
+                        mask, vblk_ref[0, 0, 0])
 
         if include_win:
             @pl.when((w >= CMP_STEPS + SEL_STEPS) & (w < TOTAL - 1))
             def _win():
                 t = jnp.clip(w - CMP_STEPS - SEL_STEPS, 0, max(WT - 1, 0))
-                kpos = win_start + t * TW + jnp.arange(TW)
-                mask = (kpos[None, :] < prefix_len) & \
-                    (kpos[None, :] > pos_r[:, None] - window) & \
-                    (kpos[None, :] <= pos_r[:, None])
-                kt = kwin_ref[0, :, 0].astype(jnp.float32)          # (TW, Dh)
-                _update(acc_ref, l_ref, m_ref, 2, q @ kt.T, mask, vwin_ref[0, :, 0])
+                kpos = win_start + t * TW + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, TW), 1)
+                mask = (kpos < prefix_len) & (kpos > pos_r - window) & \
+                    (kpos <= pos_r)
+                _update(acc_ref, l_ref, m_ref, 2, _qk(q, kwin_ref[0, 0]), mask,
+                        vwin_ref[0, 0])
 
             @pl.when(w == TOTAL - 1)
             def _draft():
-                kt = kdr_ref[0, :, 0].astype(jnp.float32)           # (Tp, Dh)
                 mask = dmask_ref[0, 0] > 0                          # (R, Tp)
-                _update(acc_ref, l_ref, m_ref, 2, q @ kt.T, mask, vdr_ref[0, :, 0])
+                _update(acc_ref, l_ref, m_ref, 2, _qk(q, kdr_ref[0, 0]), mask,
+                        vdr_ref[0, 0])
 
         @pl.when(w == TOTAL - 1)
         def _finalize():
             gts = gates_ref[0, 0, 0].astype(jnp.float32)            # (R, 3)
 
             def safe(br):
-                l = l_ref[br]
-                return jnp.where(l[:, None] > 0,
-                                 acc_ref[br] / jnp.maximum(l, 1e-30)[:, None], 0.0)
+                l = l_ref[br]                                       # (R, 1)
+                return jnp.where(l > 0, acc_ref[br] / jnp.maximum(l, 1e-30),
+                                 0.0)
 
             if combine:
                 o_cmp = (ocmp_ref[0, 0, 0].astype(jnp.float32) if has_cmp_in
@@ -160,17 +174,26 @@ def build_verify_call(*, B: int, G: int, Hkv: int, C: int, Gq: int, Dh: int,
                       include_cmp: bool = True, include_sel: bool = True,
                       include_win: bool = True, combine: bool = True,
                       has_cmp_in: bool = False, out_dtype=jnp.float32,
-                      interpret: bool = True, paged: bool = False,
+                      interpret: bool, paged: bool = False,
                       blocks_per_page: int = 1, max_pages: int = 0):
-    """Returns fn(s_merged, s_mvalid, s_own, s_pos, s_scalar[, s_pages],
+    """Returns fn(s_merged, s_mvalid, s_own, s_scalar[, s_pages], pos_rows,
     q_grp, k_cmp, v_cmp, k_blkd, v_blkd, k_win, v_win, k_draft, v_draft,
     gates_grp, dmask_grp[, o_cmp_grp]) -> o_grp (B, G, Hkv, R, Dh).
 
+    Every K/V operand is HEAD-MAJOR, so a kernel block is a (tokens, Dh)
+    tile whose last two dims satisfy the TPU (8, 128) tiling rule:
+    k_cmp/v_cmp (B, Hkv, NCBp, Dh), k_blkd/v_blkd (B, Hkv, NSB, sel_block,
+    Dh), k_win/v_win (B, Hkv, Wp, Dh), k_draft/v_draft (B, Hkv, Tp, Dh).
+    The scalar-prefetch operands are flat int32 vectors (SMEM pads every
+    trailing dim, so multi-dim tables would waste it): s_merged/s_mvalid
+    index ((b*G + g)*Hkv + h)*M + m, s_own (((b*G + g)*Hkv + h)*C + c)*M + m.
+    ``pos_rows`` (B, G, R, 1) int32 holds each query row's position.
+
     ``paged``: ``s_merged`` carries LOGICAL selection-block indices and the
-    extra ``s_pages`` (B, max_pages) scalar-prefetch input maps them to
+    extra ``s_pages`` (B*max_pages,) scalar-prefetch input maps them to
     physical pool blocks inside the slc BlockSpec index_map — the
     paged-attention gather pattern; ``NSB`` is then the PHYSICAL block count
-    of the (batch-broadcast) pool."""
+    of the (batch-broadcast) pool, whose leading dim is 1."""
     R = C * Gq
     TC = min(TC, NCBp)
     TW = min(TW, Wp)
@@ -181,67 +204,68 @@ def build_verify_call(*, B: int, G: int, Hkv: int, C: int, Gq: int, Dh: int,
         sel_block=sel_block, cmp_block=cmp_block, cmp_stride=cmp_stride,
         window=window, include_cmp=include_cmp, include_sel=include_sel,
         include_win=include_win, combine=combine, has_cmp_in=has_cmp_in,
-        paged=paged)
+        G=G, Hkv=Hkv, paged=paged)
 
     grid = (B, G, Hkv, TOTAL)
     CMP_STEPS = NCB_T if include_cmp else 0
     SEL_STEPS = M if include_sel else 0
 
     def cmp_tile(b, g, h, w, *s):
-        return (b, jnp.minimum(w, max(CMP_STEPS - 1, 0)) if include_cmp else 0, h, 0)
+        return (b, h, jnp.minimum(w, max(CMP_STEPS - 1, 0)) if include_cmp else 0, 0)
 
     def blk_tile(b, g, h, w, *s):
         s_merged = s[0]
         m = jnp.clip(w - CMP_STEPS, 0, M - 1)
+        logical = s_merged[((b * G + g) * Hkv + h) * M + m]
         if paged:
             # logical -> physical: page-table lookup + sub-block offset.
             # Invalid / unmapped blocks were already devalidated (mvalid=0)
             # by the prep layer, so the clips only pick a safe fetch target.
             # The pool is shared across the batch (leading dim 1): batch
             # coordinate 0, row identity lives in the page table.
-            blk = jnp.clip(s_merged[b, g, h, m], 0,
-                           max_pages * blocks_per_page - 1)
-            s_pages = s[5]
-            phys = s_pages[b, blk // blocks_per_page]
+            blk = jnp.clip(logical, 0, max_pages * blocks_per_page - 1)
+            s_pages = s[4]
+            phys = s_pages[b * max_pages + blk // blocks_per_page]
             blk = jnp.clip(phys * blocks_per_page + blk % blocks_per_page,
                            0, NSB - 1)
-            return (0, blk, 0, h, 0)
-        blk = jnp.clip(s_merged[b, g, h, m], 0, NSB - 1)
-        return (b, blk, 0, h, 0)
+            return (0, h, blk, 0, 0)
+        return (b, h, jnp.clip(logical, 0, NSB - 1), 0, 0)
 
     def win_tile(b, g, h, w, *s):
         t = jnp.clip(w - CMP_STEPS - SEL_STEPS, 0, max(WT - 1, 0))
-        return (b, t, h, 0)
+        return (b, h, t, 0)
+
+    def per_group(b, g, h, w, *s):
+        return (b, g, h, 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, 1, R, Dh), lambda b, g, h, w, *s: (b, g, h, 0, 0)),   # q
-        pl.BlockSpec((1, TC, 1, Dh), cmp_tile),                                    # k_cmp
-        pl.BlockSpec((1, TC, 1, Dh), cmp_tile),                                    # v_cmp
-        pl.BlockSpec((1, 1, sel_block, 1, Dh), blk_tile),                          # k blocks
-        pl.BlockSpec((1, 1, sel_block, 1, Dh), blk_tile),                          # v blocks
-        pl.BlockSpec((1, TW, 1, Dh), win_tile),                                    # k_win
-        pl.BlockSpec((1, TW, 1, Dh), win_tile),                                    # v_win
-        pl.BlockSpec((1, Tp, 1, Dh), lambda b, g, h, w, *s: (b, 0, h, 0)),         # k_draft
-        pl.BlockSpec((1, Tp, 1, Dh), lambda b, g, h, w, *s: (b, 0, h, 0)),         # v_draft
-        pl.BlockSpec((1, 1, 1, R, 3), lambda b, g, h, w, *s: (b, g, h, 0, 0)),     # gates
-        pl.BlockSpec((1, 1, R, Tp), lambda b, g, h, w, *s: (b, g, 0, 0)),          # dmask
+        pl.BlockSpec((1, 1, R, 1), lambda b, g, h, w, *s: (b, g, 0, 0)),   # pos_rows
+        pl.BlockSpec((1, 1, 1, R, Dh), per_group),                          # q
+        pl.BlockSpec((1, 1, TC, Dh), cmp_tile),                             # k_cmp
+        pl.BlockSpec((1, 1, TC, Dh), cmp_tile),                             # v_cmp
+        pl.BlockSpec((1, 1, 1, sel_block, Dh), blk_tile),                   # k blocks
+        pl.BlockSpec((1, 1, 1, sel_block, Dh), blk_tile),                   # v blocks
+        pl.BlockSpec((1, 1, TW, Dh), win_tile),                             # k_win
+        pl.BlockSpec((1, 1, TW, Dh), win_tile),                             # v_win
+        pl.BlockSpec((1, 1, Tp, Dh), lambda b, g, h, w, *s: (b, h, 0, 0)),  # k_draft
+        pl.BlockSpec((1, 1, Tp, Dh), lambda b, g, h, w, *s: (b, h, 0, 0)),  # v_draft
+        pl.BlockSpec((1, 1, 1, R, 3), per_group),                           # gates
+        pl.BlockSpec((1, 1, R, Tp), lambda b, g, h, w, *s: (b, g, 0, 0)),   # dmask
     ]
     if has_cmp_in:
-        in_specs.append(pl.BlockSpec((1, 1, 1, R, Dh),
-                                     lambda b, g, h, w, *s: (b, g, h, 0, 0)))      # o_cmp
+        in_specs.append(pl.BlockSpec((1, 1, 1, R, Dh), per_group))          # o_cmp
 
     return pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=6 if paged else 5,
+            num_scalar_prefetch=5 if paged else 4,
             grid=grid,
             in_specs=in_specs,
-            out_specs=pl.BlockSpec((1, 1, 1, R, Dh),
-                                   lambda b, g, h, w, *s: (b, g, h, 0, 0)),
+            out_specs=pl.BlockSpec((1, 1, 1, R, Dh), per_group),
             scratch_shapes=[
                 pltpu.VMEM((3, R, Dh), jnp.float32),   # acc
-                pltpu.VMEM((3, R), jnp.float32),       # l
-                pltpu.VMEM((3, R), jnp.float32),       # m
+                pltpu.VMEM((3, R, 1), jnp.float32),    # l
+                pltpu.VMEM((3, R, 1), jnp.float32),    # m
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, G, Hkv, R, Dh), out_dtype),

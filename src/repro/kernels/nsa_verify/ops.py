@@ -21,6 +21,12 @@ import numpy as np
 from repro.config import NSAConfig
 from repro.core import kvstore, overlap
 from repro.kernels.nsa_verify import kernel as K
+from repro.kernels.platform import resolve_interpret
+
+
+def _heads_major(x):
+    """(B, S, Hkv, Dh) -> (B, Hkv, S, Dh): the kernel tiles tokens x Dh."""
+    return x.transpose(0, 2, 1, 3)
 
 
 def _pad_axis(x, axis: int, target: int):
@@ -93,7 +99,7 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
                      mode: str = "exact", include_cmp: bool = True,
                      o_cmp_in=None, combine: bool = True,
                      include_sel: bool = True, include_win: bool = True,
-                     interpret: bool = True, page_table=None):
+                     interpret: Optional[bool] = None, page_table=None):
     """Fused grouped-query NSA verification (see kernel.py docstring).
 
     q: (B,T,Hq,Dh) — ALREADY rope'd and scaled by 1/sqrt(Dh).
@@ -109,6 +115,8 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
     masked, not clamped. The kernel itself is oblivious to paging — it sees
     pre-resolved physical block indices (ref parity:
     tests/test_kernels_nsa_verify.py::test_fused_paged_matches_dense).
+
+    ``interpret=None`` interprets the kernel on the CPU backend only.
     """
     B, T, Hq, Dh = q.shape
     lb = nsa.sel_block
@@ -150,6 +158,7 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
         # table supplies the per-row physical block
         k_blkd = k_cache.reshape(1, P * m, lb, Hkv, Dh)
         v_blkd = v_cache.reshape(1, P * m, lb, Hkv, Dh)
+        page_rows = page_table.astype(jnp.int32).reshape(-1)
     else:
         # cache reshaped into selection blocks for the gather index_map
         Sp = -(-S // lb) * lb
@@ -158,12 +167,15 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
         k_blkd = _pad_axis(k_cache, 1, Sp).reshape(B, NSB, lb, Hkv, Dh)
         v_blkd = _pad_axis(v_cache, 1, Sp).reshape(B, NSB, lb, Hkv, Dh)
 
+    k_blkd = k_blkd.transpose(0, 3, 1, 2, 4)           # (B|1, Hkv, NSB, lb, Dh)
+    v_blkd = v_blkd.transpose(0, 3, 1, 2, 4)
+
     # compressed cache padded to the cmp tile
     NCB = k_cmp.shape[1]
     TC = min(128, max(8, NCB))
     NCBp = -(-NCB // TC) * TC
-    k_cmp_p = _pad_axis(k_cmp, 1, NCBp)
-    v_cmp_p = _pad_axis(v_cmp, 1, NCBp)
+    k_cmp_p = _heads_major(_pad_axis(k_cmp, 1, NCBp))
+    v_cmp_p = _heads_major(_pad_axis(v_cmp, 1, NCBp))
 
     # window slice (paged: gathered from the row's pages by the store view)
     W = min(nsa.window, S)
@@ -172,13 +184,13 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
     k_win, v_win = kv_view.window(win_start, W)
     TW = min(128, max(8, W))
     Wp = -(-W // TW) * TW
-    k_win = _pad_axis(k_win, 1, Wp)
-    v_win = _pad_axis(v_win, 1, Wp)
+    k_win = _heads_major(_pad_axis(k_win, 1, Wp))
+    v_win = _heads_major(_pad_axis(v_win, 1, Wp))
 
     # draft tile + combined draft mask (tree ∧ window ∧ causal ∧ valid)
     Tp = max(8, -(-T // 8) * 8)
-    k_draft_p = _pad_axis(k_draft, 1, Tp)
-    v_draft_p = _pad_axis(v_draft, 1, Tp)
+    k_draft_p = _heads_major(_pad_axis(k_draft, 1, Tp))
+    v_draft_p = _heads_major(_pad_axis(v_draft, 1, Tp))
     dist = positions[:, :, None] - positions[:, None, :]            # (B,T,T)
     dmask = tree_mask & (dist < nsa.window) & (dist >= 0)
     dmask_g = dmask[:, gi]                                          # (B,G,C,T)
@@ -196,16 +208,20 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
         cmp_stride=nsa.cmp_stride, window=nsa.window, TC=TC, TW=TW,
         include_cmp=include_cmp, include_sel=include_sel,
         include_win=include_win, combine=combine,
-        has_cmp_in=o_cmp_in is not None, interpret=interpret,
+        has_cmp_in=o_cmp_in is not None,
+        interpret=resolve_interpret(interpret),
         paged=paged, blocks_per_page=(ps // lb if paged else 1),
         max_pages=(page_table.shape[1] if paged else 0)).items()))
     call = _cached_call(key)
 
     merged_c = jnp.clip(merged, 0, nsb_logical - 1)
-    args = [merged_c, mvalid, own, pos_grp.astype(jnp.int32), s_scalar]
+    # query row r = c*Gq + j of a group sits at its c-th query's position
+    pos_rows = jnp.repeat(pos_grp.astype(jnp.int32), Gq, axis=2)[..., None]
+    args = [merged_c.reshape(-1), mvalid.reshape(-1), own.reshape(-1),
+            s_scalar]
     if paged:
-        args.append(page_table.astype(jnp.int32))
-    args += [q_grp, k_cmp_p, v_cmp_p, k_blkd, v_blkd, k_win, v_win,
+        args.append(page_rows)
+    args += [pos_rows, q_grp, k_cmp_p, v_cmp_p, k_blkd, v_blkd, k_win, v_win,
              k_draft_p, v_draft_p, gates_grp, dmask_g]
     if o_cmp_in is not None:
         oc = o_cmp_in.reshape(B, T, Hkv, Gq, Dh)[:, gi]
@@ -228,7 +244,8 @@ def kernel_launch_count(nsa: NSAConfig, mode: str) -> int:
 def nsa_verify_kernel_layer(params, cfg, x, cache, cmp_cache, prefix_len,
                             positions, tree_mask, sel_idx=None, sel_valid=None,
                             C: int = 2, mode: str = "exact",
-                            reuse: bool = False, interpret: bool = True,
+                            reuse: bool = False,
+                            interpret: Optional[bool] = None,
                             page_table=None):
     """Full NSA verification of one layer through the Pallas kernels — the
     kernel-backed counterpart of ``models.nsa.nsa_verify_ref``.
@@ -284,7 +301,8 @@ def nsa_verify_kernel_layer(params, cfg, x, cache, cmp_cache, prefix_len,
 
 
 def nsa_verify_vanilla_layer(params, cfg, x, cache, cmp_cache, prefix_len,
-                             positions, tree_mask, interpret: bool = True):
+                             positions, tree_mask,
+                             interpret: Optional[bool] = None):
     """Vanilla-NSA baseline execution (paper Fig. 6(a)): per-branch kernels
     with intermediate branch-output materialization, no grouping (C=1), fresh
     index construction — the reference point the SSV speedups are measured

@@ -2,12 +2,14 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.config import NSAConfig
+from repro.kernels.platform import resolve_interpret
 from repro.kernels.routing import kernel as K
 from repro.models.nsa import num_sel_blocks, overlap_matrix
 
@@ -27,9 +29,10 @@ def _cached(key):
 
 
 def routing_fused(q, k_cmp, v_cmp, positions, ncb_valid, nsa: NSAConfig,
-                  kv_len: int, interpret: bool = True):
+                  kv_len: int, interpret: Optional[bool] = None):
     """q: (B,T,Hq,Dh) pre-scaled + rope'd; k_cmp/v_cmp (B,NCB,Hkv,Dh).
-    Returns (o_cmp (B,T,Hq,Dh) f32, p_slc (B,T,Hkv,NSB) f32)."""
+    Returns (o_cmp (B,T,Hq,Dh) f32, p_slc (B,T,Hkv,NSB) f32).
+    ``interpret=None`` interprets the kernel on the CPU backend only."""
     B, T, Hq, Dh = q.shape
     NCB, Hkv = k_cmp.shape[1], k_cmp.shape[2]
     Gq = Hq // Hkv
@@ -44,11 +47,14 @@ def routing_fused(q, k_cmp, v_cmp, positions, ncb_valid, nsa: NSAConfig,
     key = tuple(sorted(dict(B=B, Hkv=Hkv, R=R, Gq=Gq, Dh=Dh, NCBp=NCBp,
                             NSB=NSB, TC=TC, cmp_block=nsa.cmp_block,
                             cmp_stride=nsa.cmp_stride,
-                            interpret=interpret).items()))
+                            interpret=resolve_interpret(interpret)).items()))
     call = _cached(key)
     s_scalar = jnp.stack([jnp.asarray(ncb_valid, jnp.int32)])
-    o, p_slc = call(positions.astype(jnp.int32), s_scalar, q_l,
-                    _pad_axis(k_cmp, 1, NCBp), _pad_axis(v_cmp, 1, NCBp), M)
+    # kernel row r = t*Gq + j sits at draft token t's position
+    pos_rows = jnp.repeat(positions.astype(jnp.int32), Gq, axis=1)[..., None]
+    heads_major = lambda x: _pad_axis(x, 1, NCBp).transpose(0, 2, 1, 3)
+    o, p_slc = call(s_scalar, pos_rows, q_l, heads_major(k_cmp),
+                    heads_major(v_cmp), M)
     o = o.reshape(B, Hkv, T, Gq, Dh).transpose(0, 2, 1, 3, 4).reshape(
         B, T, Hq, Dh)
     return o, p_slc.transpose(0, 2, 1, 3)
