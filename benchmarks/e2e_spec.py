@@ -2,8 +2,6 @@
 shapes (D, k), SSV variants vs the autoregressive NSA decode baseline.
 
 Also the serving-hot-path regression harness:
-  * per-step phase breakdown (draft / verify+accept / commit wall time) from
-    an instrumented engine run;
   * host-transfer accounting — asserts the spec-decode loop no longer pulls
     the (T, vocab) verification logits to the host (only path tokens /
     counts / bonus cross);
@@ -73,23 +71,12 @@ def main(csv=None, grid=((2, 2), (3, 2), (4, 2), (3, 4)), tokens=48,
                 "tok_s": tps, "speedup_vs_ar": tps / max(base_tps, 1e-9),
                 "mean_accepted": res.mean_accepted}
 
-    # ---- per-step phase breakdown (instrumented run: sync between phases)
-    ssv0 = SSVConfig(tree_depth=grid[0][0], tree_width=grid[0][1],
-                     traversal="bfs", group_size=2, group_mode="exact")
-    eng = engine_lib.SSVEngine(tp, tcfg, dp, dcfg, _serve_cfg(ssv0, tokens),
-                               instrument=True)
-    res = eng.generate(prompt, max_new_tokens=tokens)
-    phases = {}
-    for st in res.steps[1:] or res.steps:     # drop the compile step
-        for k2, v in (st.phases or {}).items():
-            phases.setdefault(k2, []).append(v)
-    breakdown = {k2: float(np.mean(v)) for k2, v in phases.items()}
-    for k2, v in breakdown.items():
-        csv.row(f"step_phase_{k2}", v * 1e6, "mean per-step seconds (instrumented)")
-    report["step_phase_breakdown_s"] = breakdown
-
     # ---- host-transfer accounting: the fused step returns a few ints, not
     # (T, vocab) logits
+    ssv0 = SSVConfig(tree_depth=grid[0][0], tree_width=grid[0][1],
+                     traversal="bfs", group_size=2, group_mode="exact")
+    eng = engine_lib.SSVEngine(tp, tcfg, dp, dcfg, _serve_cfg(ssv0, tokens))
+    res = eng.generate(prompt, max_new_tokens=tokens)
     T = ssv0.num_draft_tokens() + 1
     per_step = engine_lib.step_host_transfer_elems(ssv0)
     logits_elems = T * tcfg.vocab_size

@@ -25,6 +25,7 @@ before any phase: it never falls back to the CPU.
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import json
 import sys
@@ -42,44 +43,25 @@ KERNEL_CONTEXT = 16384
 LOGITS_REL_L2_TOL = 5e-2
 KERNEL_REL_MAX_TOL = 2e-2
 
-COMPILE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
-                  "/jax/core/compile/jaxpr_to_mlir_module_duration")
-BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+def compile_totals():
+    """(trace+lower s, backend compile s, cache load s, persistent-cache
+    requests, hits) of the process so far, from ``repro.obs``."""
+    from repro import obs
+    snap = obs.snapshot()
+    sec = collections.defaultdict(float)
+    for c in snap["compiles"]:
+        sec[c.phase] += c.seconds
+    cnt = snap["counters"]
+    return (sec["trace"] + sec["lower"], sec["compile"], sec["cache_load"],
+            cnt.get("compile_cache.requests", 0),
+            cnt.get("compile_cache.hits", 0))
 
 
-class CompileClock:
-    """Seconds JAX spends tracing, lowering and compiling, plus persistent
-    compile-cache requests and hits, accumulated from jax.monitoring."""
-
-    def __init__(self, monitoring):
-        self.trace_lower_s = 0.0
-        self.backend_compile_s = 0.0
-        self.cache_requests = 0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._duration)
-        monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == BACKEND_COMPILE_EVENT:
-            self.backend_compile_s += duration
-        elif event in COMPILE_EVENTS:
-            self.trace_lower_s += duration
-
-    def _event(self, event, **_):
-        if event == "/jax/compilation_cache/compile_requests_use_cache":
-            self.cache_requests += 1
-        elif event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def snapshot(self):
-        return (self.trace_lower_s, self.backend_compile_s,
-                self.cache_requests, self.cache_hits)
-
-
-def timed_phase(name, clock, fn):
+def timed_phase(name, fn):
     """Run ``fn``; print its wall, compile and run seconds. Returns
     (ok, result)."""
-    before = clock.snapshot()
+    before = compile_totals()
     t0 = time.perf_counter()
     try:
         result = fn()
@@ -88,11 +70,11 @@ def timed_phase(name, clock, fn):
         traceback.print_exc()
         result, ok = None, False
     wall = time.perf_counter() - t0
-    tl, bc, req, hit = (a - b for a, b in zip(clock.snapshot(), before))
+    tl, bc, cl, req, hit = (a - b for a, b in zip(compile_totals(), before))
     print(f"[{name}] {'PASS' if ok else 'FAIL'}: wall {wall:.2f}s, "
-          f"trace+lower {tl:.2f}s, backend compile {bc:.2f}s, "
-          f"run {max(wall - tl - bc, 0.0):.2f}s; persistent cache "
-          f"{hit} hits / {req - hit} misses", flush=True)
+          f"trace+lower {tl:.2f}s, backend compile {bc:.2f}s, cache loads "
+          f"{cl:.2f}s, run {max(wall - tl - bc - cl, 0.0):.2f}s; persistent "
+          f"cache {hit} hits / {req - hit} misses", flush=True)
     return ok, result
 
 
@@ -300,6 +282,7 @@ def main(argv=None) -> int:
     sys.path.insert(0, str(Path(__file__).resolve().parent / "src"))
     try:
         from repro import configs
+        from repro import obs  # noqa: F401  (listens for compiles from here on)
         from repro.compile_cache import enable_compile_cache
     except ImportError as e:
         print(f"chip_smoke: the repro package is not beside this script: {e}",
@@ -307,17 +290,14 @@ def main(argv=None) -> int:
         return 2
     print(f"device: {dev.platform} {dev.device_kind} x{len(devices)}; "
           f"compile cache {enable_compile_cache()}", flush=True)
-    clock = CompileClock(jax.monitoring)
     cfg = configs.get_config("ssv-nsa-1b")
 
-    ok_serve, _ = timed_phase("serve", clock,
-                              lambda: serve_phase(cfg, args.seed))
-    ok_kernel, _ = timed_phase("kernel", clock,
-                               lambda: kernel_phase(cfg, args.seed))
-    print(f"total: trace+lower {clock.trace_lower_s:.2f}s, backend compile "
-          f"{clock.backend_compile_s:.2f}s; persistent cache "
-          f"{clock.cache_hits} hits / "
-          f"{clock.cache_requests - clock.cache_hits} misses", flush=True)
+    ok_serve, _ = timed_phase("serve", lambda: serve_phase(cfg, args.seed))
+    ok_kernel, _ = timed_phase("kernel", lambda: kernel_phase(cfg, args.seed))
+    tl, bc, cl, req, hit = compile_totals()
+    print(f"total: trace+lower {tl:.2f}s, backend compile {bc:.2f}s, cache "
+          f"loads {cl:.2f}s; persistent cache {hit} hits / {req - hit} "
+          "misses", flush=True)
     if not (ok_serve and ok_kernel):
         print("chip_smoke: FAILED", file=sys.stderr)
         return 1

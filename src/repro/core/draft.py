@@ -102,7 +102,8 @@ def expand_tree(verify_fn, draft_cfg: ModelConfig, draft_caches, topo: TreeTopol
         # its parent's draft logits
         level = np.where(depths == d + 1)[0]
         kmax = int(rank[level].max()) + 1 if len(level) else 1
-        _, topk_idx = jax.lax.top_k(logits, kmax)                    # (B, T, kmax)
+        with jax.named_scope("ssv.draft.topk"):
+            _, topk_idx = jax.lax.top_k(logits, kmax)                # (B, T, kmax)
         par = jnp.asarray(topo.parents[level])
         rk = jnp.asarray(rank[level])
         picked = topk_idx[:, par, rk]                                # (B, |level|)

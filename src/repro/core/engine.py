@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig, ServeConfig, SSVConfig
 from repro.core import accept as accept_lib
 from repro.core import draft as draft_lib
@@ -57,35 +58,35 @@ from repro.models import model
 # tests/test_engine_batched.py::test_jit_cache_keys_by_value).
 @functools.lru_cache(maxsize=64)
 def jit_verify(cfg: ModelConfig, ssv: Optional[SSVConfig]):
-    def f(params, caches, tokens, positions, tmask, parents):
+    def ssv_verify(params, caches, tokens, positions, tmask, parents):
         return model.verify_step(params, cfg, caches, tokens, positions, tmask,
                                  parents, ssv)
-    return jax.jit(f)
+    return jax.jit(ssv_verify)
 
 
 @functools.lru_cache(maxsize=64)
 def jit_commit(cfg: ModelConfig):
     # caches donated: the commit's output KV buffers alias the inputs —
     # no second max_context-sized allocation per step.
-    def f(params, caches, updates, accepted, n_accepted):
+    def ssv_commit(params, caches, updates, accepted, n_accepted):
         return model.commit(params, cfg, caches, updates, accepted, n_accepted)
-    return jax.jit(f, donate_argnums=(1,))
+    return jax.jit(ssv_commit, donate_argnums=(1,))
 
 
 @functools.lru_cache(maxsize=64)
 def jit_prefill(cfg: ModelConfig, max_len: int):
     # prefill builds the caches from scratch — there is no input cache buffer
     # to donate; the prompt token array is tiny, so nothing else is worth it.
-    def f(params, tokens):
+    def ssv_prefill(params, tokens):
         return model.prefill(params, cfg, tokens, max_len)
-    return jax.jit(f)
+    return jax.jit(ssv_prefill)
 
 
 @functools.lru_cache(maxsize=64)
 def jit_decode(cfg: ModelConfig):
-    def f(params, caches, tokens):
+    def ssv_decode(params, caches, tokens):
         return model.decode_step(params, cfg, caches, tokens)
-    return jax.jit(f)
+    return jax.jit(ssv_decode)
 
 
 @functools.lru_cache(maxsize=64)
@@ -98,8 +99,8 @@ def jit_verify_accept(cfg: ModelConfig, ssv: SSVConfig, greedy: bool,
     the caller alongside the (donated, updated-in-place) caches — the
     (T, vocab) logits tensor stays on device.
 
-    Greedy signature:     f(params, caches, tokens)
-    Stochastic signature: f(params, caches, tokens, node_q, accept_u, bonus_u)
+    Greedy signature:     (params, caches, tokens)
+    Stochastic signature: (params, caches, tokens, node_q, accept_u, bonus_u)
     Returns (new_caches, path (pad,), tokens (pad+1,), bonus, n_accepted_path)
     where n_accepted_path counts accepted DRAFT nodes (excl. root/bonus) and
     path/n include the pending root as commit expects.
@@ -125,17 +126,17 @@ def jit_verify_accept(cfg: ModelConfig, ssv: SSVConfig, greedy: bool,
         return new_caches, path, out_tokens, bonus, n_acc
 
     if greedy:
-        def f(params, caches, tokens):
+        def ssv_verify_accept(params, caches, tokens):
             return core(params, caches, tokens,
                         lambda tk, lg: accept_lib.greedy_tree_accept_device(
                             child_mat, maxd, tk, lg))
     else:
-        def f(params, caches, tokens, node_q, accept_u, bonus_u):
+        def ssv_verify_accept(params, caches, tokens, node_q, accept_u, bonus_u):
             return core(params, caches, tokens,
                         lambda tk, lg: accept_lib.stochastic_tree_accept_device(
                             child_mat, maxd, tk, lg, node_q[0], accept_u,
                             bonus_u, temperature))
-    return jax.jit(f, donate_argnums=(1,))
+    return jax.jit(ssv_verify_accept, donate_argnums=(1,))
 
 
 def _resolve_store(serve_cfg: ServeConfig, target_cfg: ModelConfig) -> kvstore.KVStoreConfig:
@@ -191,14 +192,18 @@ def kernel_cache_stats() -> Dict[str, int]:
     next to ``kv_cache_bytes``: the fused-verify kernel build cache
     (``kernels/nsa_verify/ops._cached_call``) and the (T, C) query-group
     layout cache (``overlap.group_queries``). Both caches are shared by
-    every engine in the process."""
+    every engine in the process and count their lookups and builds into
+    ``obs.counters()``; the ``*_cached`` entries are the caches' sizes."""
     from repro.kernels.nsa_verify import ops as nsa_ops
-    vc = nsa_ops.verify_call_cache_info()
-    gq = overlap_lib.group_queries.cache_info()
-    return {"verify_call_hits": vc.hits, "verify_call_misses": vc.misses,
-            "verify_call_cached": vc.currsize,
-            "group_layout_hits": gq.hits, "group_layout_misses": gq.misses,
-            "group_layout_cached": gq.currsize}
+    c = obs.counters()
+    out = {}
+    for key, size in (("verify_call", nsa_ops.verify_call_cache_info().currsize),
+                      ("group_layout", overlap_lib.group_queries.cache_info().currsize)):
+        misses = c.get(f"kernel.{key}.builds", 0)
+        out[f"{key}_hits"] = c.get(f"kernel.{key}.lookups", 0) - misses
+        out[f"{key}_misses"] = misses
+        out[f"{key}_cached"] = size
+    return out
 
 
 def step_host_transfer_elems(ssv: SSVConfig) -> int:
@@ -219,7 +224,6 @@ class StepStats:
     gamma: int             # draft tokens verified
     strategy: SSVConfig
     host_elems: int = 0    # device->host elements fetched this step
-    phases: Optional[Dict[str, float]] = None  # draft/verify_accept/commit (instrumented)
 
 
 @dataclasses.dataclass
@@ -239,16 +243,11 @@ class GenerationResult:
 
 
 class SSVEngine:
-    """Single-sequence (B=1 per stream) speculative serving engine.
-
-    ``instrument=True`` adds per-phase wall times (draft / verify+accept /
-    commit) to StepStats by blocking between phases — measurement only, it
-    serializes the step and should stay off in production paths.
-    """
+    """Single-sequence (B=1 per stream) speculative serving engine."""
 
     def __init__(self, target_params, target_cfg: ModelConfig, draft_params,
                  draft_cfg: ModelConfig, serve_cfg: ServeConfig, planner=None,
-                 rng_seed: int = 0, instrument: bool = False):
+                 rng_seed: int = 0):
         if getattr(planner, "is_batch_planner", False):
             raise ValueError(
                 "BatchPlanner plans bucket-local execution groups over a "
@@ -259,7 +258,6 @@ class SSVEngine:
         self.serve = serve_cfg
         self.planner = planner
         self.rng = np.random.default_rng(rng_seed)
-        self.instrument = instrument
         self.t_caches = None
         self.d_caches = None
         self.pending: Optional[int] = None
@@ -321,7 +319,6 @@ class SSVEngine:
                               ssv.tree_budget)
         greedy = self.serve.temperature == 0.0
         t0 = time.perf_counter()
-        phases: Optional[Dict[str, float]] = {} if self.instrument else None
         pending = jnp.asarray([self.pending], jnp.int32)
 
         dverify = jit_verify(self.dcfg, None)
@@ -329,13 +326,9 @@ class SSVEngine:
             lambda caches, tk, pos, tm, par: dverify(self.dp, caches, tk, pos, tm, par),
             self.dcfg, self.d_caches, topo, pending,
             temperature=self.serve.temperature)
-        if phases is not None:
-            jax.block_until_ready(tokens)
-            phases["draft"] = time.perf_counter() - t0
 
         T = topo.num_nodes
         step_fn = jit_verify_accept(self.tcfg, ssv, greedy, self.serve.temperature)
-        t1 = time.perf_counter()
         if greedy:
             self.t_caches, path, out_tokens, bonus, n_acc = step_fn(
                 self.tp, self.t_caches, tokens)
@@ -345,11 +338,7 @@ class SSVEngine:
                 self.tp, self.t_caches, tokens,
                 node_q, jnp.asarray(accept_u, jnp.float32),
                 jnp.float32(bonus_u))
-        if phases is not None:
-            jax.block_until_ready(out_tokens)
-            phases["verify_accept"] = time.perf_counter() - t1
 
-        t2 = time.perf_counter()
         # draft commit consumes the on-device path — no host round-trip
         self.d_caches = jit_commit(self.dcfg)(
             self.dp, self.d_caches, d_updates, path[None], (n_acc + 1)[None])
@@ -358,14 +347,10 @@ class SSVEngine:
         emitted = np.asarray(out_tokens[: n + 1])
         self.pending = int(emitted[-1])
         self.committed_len += n + 1
-        if phases is not None:
-            jax.block_until_ready(jax.tree.leaves(self.d_caches))
-            phases["commit"] = time.perf_counter() - t2
 
         dt = time.perf_counter() - t0
         stats = StepStats(accepted=n, emitted=n + 1, latency_s=dt, gamma=T - 1,
-                          strategy=ssv, host_elems=emitted.size + 2,
-                          phases=phases)
+                          strategy=ssv, host_elems=emitted.size + 2)
         if self.planner is not None:
             self.planner.observe(accepted=n, latency_s=dt)
         return [int(t) for t in emitted], stats
@@ -440,10 +425,10 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
     ``admit_pending`` before stepping — so one launch serves a mix of
     freshly-admitted and mid-generation rows without touching other rows.
 
-    Greedy signature:     f(tp, dp, t_segs, t_len, d_segs, d_len, pending,
-                            active, admit_mask, admit_len, admit_pending)
-    Stochastic signature: f(..., admit_pending, accept_u (R,rounds,kmax),
-                            bonus_u (R,))
+    Greedy signature:     (tp, dp, t_segs, t_len, d_segs, d_len, pending,
+                           active, admit_mask, admit_len, admit_pending)
+    Stochastic signature: (..., admit_pending, accept_u (R,rounds,kmax),
+                           bonus_u (R,))
       -> (t_segs', t_len', d_segs', d_len', tokens (R, pad+1), n_acc (R,))
     where segs are the caches' "segments" pytrees with leaf batch axis 1.
 
@@ -453,6 +438,10 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
     the vmap), so the per-row trace runs ``commit_paged_prepare`` only and
     the pool scatters are issued once at batch level, where rows cannot
     alias (the allocator never double-assigns a page).
+
+    The phases carry named scopes (``ssv.admit_reset``, ``ssv.draft``,
+    ``ssv.verify``, ``ssv.accept``, ``ssv.commit``) in every op's metadata,
+    so a profiler trace puts each device op under its phase.
     """
     topo = build_topology(ssv.tree_depth, ssv.tree_width, ssv.traversal,
                           ssv.tree_budget)
@@ -472,20 +461,26 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
                         "pages": pages_row[None]}
             d_caches = {"segments": rebatch(d_segs), "length": d_len,
                         "pages": pages_row[None]}
-            tokens, node_q, d_updates = draft_lib.expand_tree(
-                lambda caches, tk, pos, tm, par: model.verify_step(
-                    dp, dcfg, caches, tk, pos, tm, par, None),
-                dcfg, d_caches, topo, pending[None], temperature=temperature)
-            positions = (depths[None] + t_len).astype(jnp.int32)
-            logits, t_updates = model.verify_step(
-                tp, tcfg, t_caches, tokens, positions, tmask[None], parents, ssv)
-            path, out_tokens, bonus, n_acc = accept_fn(tokens[0], logits[0],
-                                                       node_q[0])
-            n_commit = jnp.where(active, n_acc + 1, 0)[None]
-            t_prep, t_new_len = model.commit_paged_prepare(
-                tp, tcfg, t_caches, t_updates, path[None], n_commit)
-            d_prep, d_new_len = model.commit_paged_prepare(
-                dp, dcfg, d_caches, d_updates, path[None], n_commit)
+            with jax.named_scope("ssv.draft"):
+                tokens, node_q, d_updates = draft_lib.expand_tree(
+                    lambda caches, tk, pos, tm, par: model.verify_step(
+                        dp, dcfg, caches, tk, pos, tm, par, None),
+                    dcfg, d_caches, topo, pending[None],
+                    temperature=temperature)
+            with jax.named_scope("ssv.verify"):
+                positions = (depths[None] + t_len).astype(jnp.int32)
+                logits, t_updates = model.verify_step(
+                    tp, tcfg, t_caches, tokens, positions, tmask[None],
+                    parents, ssv)
+            with jax.named_scope("ssv.accept"):
+                path, out_tokens, bonus, n_acc = accept_fn(
+                    tokens[0], logits[0], node_q[0])
+            with jax.named_scope("ssv.commit"):
+                n_commit = jnp.where(active, n_acc + 1, 0)[None]
+                t_prep, t_new_len = model.commit_paged_prepare(
+                    tp, tcfg, t_caches, t_updates, path[None], n_commit)
+                d_prep, d_new_len = model.commit_paged_prepare(
+                    dp, dcfg, d_caches, d_updates, path[None], n_commit)
             strip = lambda tree: jax.tree.map(lambda a: a[:, 0], tree)
             return (strip(t_prep), t_new_len, strip(d_prep), d_new_len,
                     out_tokens, n_acc)
@@ -508,11 +503,13 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
                                     bonus_u, temperature))
             extra_axes = (0, 0)
 
-        def f(tp, dp, t_segs, t_len, d_segs, d_len, pages, pending, active,
-              admit_mask, admit_len, admit_pending, *rest):
-            t_len = jnp.where(admit_mask, admit_len, t_len)
-            d_len = jnp.where(admit_mask, admit_len, d_len)
-            pending = jnp.where(admit_mask, admit_pending, pending)
+        def ssv_batched_step(tp, dp, t_segs, t_len, d_segs, d_len, pages,
+                             pending, active, admit_mask, admit_len,
+                             admit_pending, *rest):
+            with jax.named_scope("ssv.admit_reset"):
+                t_len = jnp.where(admit_mask, admit_len, t_len)
+                d_len = jnp.where(admit_mask, admit_len, d_len)
+                pending = jnp.where(admit_mask, admit_pending, pending)
             # pool leaves are shared (unmapped); every other cache leaf is
             # row-batched on axis 1 as in the dense step
             t_axes = kvstore.map_segments(t_segs, lambda _: None, lambda _: 1)
@@ -524,14 +521,15 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
             (t_prep, t_new_len, d_prep, d_new_len, out_tokens,
              n_acc) = vstep(tp, dp, t_segs, t_len, d_segs, d_len, pages,
                             pending, active, *rest)
-            n_commit = jnp.where(active, n_acc + 1, 0)
-            new_t = model.commit_apply_paged(t_segs, t_prep, pages, t_len,
-                                             n_commit)
-            new_d = model.commit_apply_paged(d_segs, d_prep, pages, d_len,
-                                             n_commit)
+            with jax.named_scope("ssv.commit"):
+                n_commit = jnp.where(active, n_acc + 1, 0)
+                new_t = model.commit_apply_paged(t_segs, t_prep, pages, t_len,
+                                                 n_commit)
+                new_d = model.commit_apply_paged(d_segs, d_prep, pages, d_len,
+                                                 n_commit)
             return new_t, t_new_len, new_d, d_new_len, out_tokens, n_acc
 
-        return jax.jit(f, donate_argnums=(2, 3, 4, 5))
+        return jax.jit(ssv_batched_step, donate_argnums=(2, 3, 4, 5))
 
     def row_core(tp, dp, t_segs, t_len, d_segs, d_len, pending, active,
                  accept_fn):
@@ -539,18 +537,25 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
                     "length": t_len}
         d_caches = {"segments": jax.tree.map(lambda a: a[:, None], d_segs),
                     "length": d_len}
-        tokens, node_q, d_updates = draft_lib.expand_tree(
-            lambda caches, tk, pos, tm, par: model.verify_step(
-                dp, dcfg, caches, tk, pos, tm, par, None),
-            dcfg, d_caches, topo, pending[None], temperature=temperature)
-        positions = (depths[None] + t_len).astype(jnp.int32)
-        logits, t_updates = model.verify_step(
-            tp, tcfg, t_caches, tokens, positions, tmask[None], parents, ssv)
-        path, out_tokens, bonus, n_acc = accept_fn(tokens[0], logits[0],
-                                                   node_q[0])
-        n_commit = jnp.where(active, n_acc + 1, 0)[None]
-        new_t = model.commit(tp, tcfg, t_caches, t_updates, path[None], n_commit)
-        new_d = model.commit(dp, dcfg, d_caches, d_updates, path[None], n_commit)
+        with jax.named_scope("ssv.draft"):
+            tokens, node_q, d_updates = draft_lib.expand_tree(
+                lambda caches, tk, pos, tm, par: model.verify_step(
+                    dp, dcfg, caches, tk, pos, tm, par, None),
+                dcfg, d_caches, topo, pending[None], temperature=temperature)
+        with jax.named_scope("ssv.verify"):
+            positions = (depths[None] + t_len).astype(jnp.int32)
+            logits, t_updates = model.verify_step(
+                tp, tcfg, t_caches, tokens, positions, tmask[None], parents,
+                ssv)
+        with jax.named_scope("ssv.accept"):
+            path, out_tokens, bonus, n_acc = accept_fn(tokens[0], logits[0],
+                                                       node_q[0])
+        with jax.named_scope("ssv.commit"):
+            n_commit = jnp.where(active, n_acc + 1, 0)[None]
+            new_t = model.commit(tp, tcfg, t_caches, t_updates, path[None],
+                                 n_commit)
+            new_d = model.commit(dp, dcfg, d_caches, d_updates, path[None],
+                                 n_commit)
         return (jax.tree.map(lambda a: a[:, 0], new_t["segments"]),
                 new_t["length"],
                 jax.tree.map(lambda a: a[:, 0], new_d["segments"]),
@@ -575,15 +580,16 @@ def jit_batched_step(tcfg: ModelConfig, dcfg: ModelConfig, ssv: SSVConfig,
 
     vstep = jax.vmap(row_step, in_axes=in_axes, out_axes=(1, 0, 1, 0, 0, 0))
 
-    def f(tp, dp, t_segs, t_len, d_segs, d_len, pending, active,
-          admit_mask, admit_len, admit_pending, *rest):
-        t_len = jnp.where(admit_mask, admit_len, t_len)
-        d_len = jnp.where(admit_mask, admit_len, d_len)
-        pending = jnp.where(admit_mask, admit_pending, pending)
+    def ssv_batched_step(tp, dp, t_segs, t_len, d_segs, d_len, pending,
+                         active, admit_mask, admit_len, admit_pending, *rest):
+        with jax.named_scope("ssv.admit_reset"):
+            t_len = jnp.where(admit_mask, admit_len, t_len)
+            d_len = jnp.where(admit_mask, admit_len, d_len)
+            pending = jnp.where(admit_mask, admit_pending, pending)
         return vstep(tp, dp, t_segs, t_len, d_segs, d_len, pending, active,
                      *rest)
 
-    return jax.jit(f, donate_argnums=(2, 3, 4, 5))
+    return jax.jit(ssv_batched_step, donate_argnums=(2, 3, 4, 5))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -998,31 +1004,45 @@ class BatchedSSVEngine:
             raise ValueError(f"slot {slot} out of range for batch {self.batch}")
         prompt = np.asarray(prompt)
         self._check_prompt(prompt)
+        with obs.span("ssv.admit", slot=int(slot), prompt_len=len(prompt)):
+            self._admit(slot, prompt, max_new_tokens)
+        obs.count("ssv.admissions")
+        obs.count("ssv.prefill_tokens", len(prompt) - 1)
+
+    def _admit(self, slot: int, prompt: np.ndarray, max_new_tokens: int):
         max_len = self.serve.max_context
         toks = jnp.asarray(prompt, jnp.int32)[None]
-        _, tc = jit_prefill(self.tcfg, max_len)(self.tp, toks[:, :-1])
-        _, dc = jit_prefill(self.dcfg, max_len)(self.dp, toks[:, :-1])
+        with obs.span("ssv.prefill", model="target"):
+            _, tc = jit_prefill(self.tcfg, max_len)(self.tp, toks[:, :-1])
+        with obs.span("ssv.prefill", model="draft"):
+            _, dc = jit_prefill(self.dcfg, max_len)(self.dp, toks[:, :-1])
         if self.store.is_paged:
-            self._free_slot_pages(slot)      # stale mapping of a past tenant
-            need = self.pages_for(len(prompt), max_new_tokens)
-            pg = self.allocator.alloc(need)
-            if pg is None:
-                raise RuntimeError(
-                    f"page pool exhausted admitting into slot {slot}: need "
-                    f"{need} pages, {self.allocator.free_count} free — gate "
-                    "admission on free-page headroom (Scheduler pages_for)")
-            self._slot_pages[slot] = pg
-            row = np.full((self._max_pages,), -1, np.int32)
-            row[:need] = pg
-            self.pages[slot] = row
-            rowj = jnp.asarray(row)
-            self.t_segs = kvstore.admit_row_paged(self.t_segs, tc["segments"],
-                                                  jnp.int32(slot), rowj)
-            self.d_segs = kvstore.admit_row_paged(self.d_segs, dc["segments"],
-                                                  jnp.int32(slot), rowj)
+            with obs.span("ssv.kv.alloc"):
+                self._free_slot_pages(slot)    # stale mapping of a past tenant
+                need = self.pages_for(len(prompt), max_new_tokens)
+                pg = self.allocator.alloc(need)
+                if pg is None:
+                    raise RuntimeError(
+                        f"page pool exhausted admitting into slot {slot}: "
+                        f"need {need} pages, {self.allocator.free_count} free "
+                        "— gate admission on free-page headroom (Scheduler "
+                        "pages_for)")
+                self._slot_pages[slot] = pg
+                row = np.full((self._max_pages,), -1, np.int32)
+                row[:need] = pg
+                self.pages[slot] = row
+            with obs.span("ssv.kv.scatter"):
+                rowj = jnp.asarray(row)
+                self.t_segs = kvstore.admit_row_paged(
+                    self.t_segs, tc["segments"], jnp.int32(slot), rowj)
+                self.d_segs = kvstore.admit_row_paged(
+                    self.d_segs, dc["segments"], jnp.int32(slot), rowj)
         else:
-            self.t_segs = admit_row_segments(self.t_segs, tc["segments"], slot)
-            self.d_segs = admit_row_segments(self.d_segs, dc["segments"], slot)
+            with obs.span("ssv.kv.scatter"):
+                self.t_segs = admit_row_segments(self.t_segs, tc["segments"],
+                                                 slot)
+                self.d_segs = admit_row_segments(self.d_segs, dc["segments"],
+                                                 slot)
         self._admit_mask[slot] = True
         self._admit_len[slot] = len(prompt) - 1
         self._admit_pending[slot] = int(prompt[-1])
@@ -1036,7 +1056,11 @@ class BatchedSSVEngine:
         n_accepted (R,)); inactive rows commit nothing (length frozen). Rows
         admitted since the last step have their device length / pending root
         reset inside this same launch (per-row admission mask), so the launch
-        serves freshly-admitted and mid-generation rows together."""
+        serves freshly-admitted and mid-generation rows together.
+
+        Recorded (``repro.obs``) as an ``ssv.step`` span whose children are
+        ``ssv.step.prepare`` (host arrays), ``.launch``, ``.sync`` (the wait
+        for the device's tokens) and ``.update`` (host state)."""
         if strategy is None and getattr(self.planner, "is_batch_planner",
                                         False):
             raise ValueError(
@@ -1044,34 +1068,49 @@ class BatchedSSVEngine:
                 "strategy= explicitly, or serve through serve_continuous / "
                 "step_group so each execution group gets its bucket's plan")
         ssv = strategy or (self.planner.current() if self.planner else self.serve.ssv)
-        greedy = self.serve.temperature == 0.0
-        step_fn = jit_batched_step(self.tcfg, self.dcfg, ssv, greedy,
-                                   self.serve.temperature, self.store)
-        args = [self.tp, self.dp, self.t_segs, self.t_len, self.d_segs,
-                self.d_len]
-        if self.store.is_paged:
-            args.append(jnp.asarray(self.pages))
-        args += [jnp.asarray(self.pending), jnp.asarray(active),
-                 jnp.asarray(self._admit_mask),
-                 jnp.asarray(self._admit_len, jnp.int32),
-                 jnp.asarray(self._admit_pending, jnp.int32)]
-        self._admit_mask = np.zeros_like(self._admit_mask)
-        if not greedy:
-            topo = build_topology(ssv.tree_depth, ssv.tree_width,
-                                  ssv.traversal, ssv.tree_budget)
-            us = [accept_lib.draw_uniforms(topo, self.rng)
-                  for _ in range(self.batch)]
-            args.append(jnp.asarray(np.stack([u for u, _ in us]), jnp.float32))
-            args.append(jnp.asarray([b for _, b in us], jnp.float32))
-        (self.t_segs, self.t_len, self.d_segs, self.d_len, out_tokens,
-         n_acc) = step_fn(*args)
-        # per-step host transfer: (R, pad+1) token ids + (R,) counts
-        toks_np = np.asarray(out_tokens)
-        n_np = np.asarray(n_acc)
         live = np.asarray(active, bool)
-        self.pending = np.where(live, toks_np[np.arange(self.batch), n_np],
-                                self.pending).astype(np.int32)
-        self.committed_len = self.committed_len + np.where(live, n_np + 1, 0)
+        rows = int(live.sum())
+        with obs.span("ssv.step", rows=rows):
+            toks_np, n_np = self._step(live, ssv)
+        obs.count("ssv.steps")
+        obs.count("ssv.rows_stepped", rows)
+        obs.count("ssv.tokens_committed", int((n_np + 1)[live].sum()))
+        return toks_np, n_np
+
+    def _step(self, live: np.ndarray, ssv: SSVConfig):
+        with obs.span("ssv.step.prepare"):
+            greedy = self.serve.temperature == 0.0
+            step_fn = jit_batched_step(self.tcfg, self.dcfg, ssv, greedy,
+                                       self.serve.temperature, self.store)
+            args = [self.tp, self.dp, self.t_segs, self.t_len, self.d_segs,
+                    self.d_len]
+            if self.store.is_paged:
+                args.append(jnp.asarray(self.pages))
+            args += [jnp.asarray(self.pending), jnp.asarray(live),
+                     jnp.asarray(self._admit_mask),
+                     jnp.asarray(self._admit_len, jnp.int32),
+                     jnp.asarray(self._admit_pending, jnp.int32)]
+            self._admit_mask = np.zeros_like(self._admit_mask)
+            if not greedy:
+                topo = build_topology(ssv.tree_depth, ssv.tree_width,
+                                      ssv.traversal, ssv.tree_budget)
+                us = [accept_lib.draw_uniforms(topo, self.rng)
+                      for _ in range(self.batch)]
+                args.append(jnp.asarray(np.stack([u for u, _ in us]),
+                                        jnp.float32))
+                args.append(jnp.asarray([b for _, b in us], jnp.float32))
+        with obs.span("ssv.step.launch"):
+            (self.t_segs, self.t_len, self.d_segs, self.d_len, out_tokens,
+             n_acc) = step_fn(*args)
+        # per-step host transfer: (R, pad+1) token ids + (R,) counts
+        with obs.span("ssv.step.sync"):
+            toks_np = np.asarray(out_tokens)
+            n_np = np.asarray(n_acc)
+        with obs.span("ssv.step.update"):
+            self.pending = np.where(live, toks_np[np.arange(self.batch), n_np],
+                                    self.pending).astype(np.int32)
+            self.committed_len = self.committed_len + np.where(live, n_np + 1,
+                                                               0)
         return toks_np, n_np
 
     def step_group(self, rows: Sequence[int],
@@ -1101,59 +1140,75 @@ class BatchedSSVEngine:
         for s in rows:
             if not 0 <= s < self.batch:
                 raise ValueError(f"row {s} out of range for batch {self.batch}")
+        with obs.span("ssv.step_group", rows=len(rows)):
+            toks_np, n_np = self._step_group(rows, strategy)
+        obs.count("ssv.steps")
+        obs.count("ssv.rows_stepped", len(rows))
+        obs.count("ssv.tokens_committed", int((n_np + 1).sum()))
+        return toks_np, n_np
+
+    def _step_group(self, rows: List[int], strategy: SSVConfig):
         r = len(rows)
         # fast path: a group covering the whole batch (the common case under
         # the bucket-homogeneous admission policy) steps the engine caches
         # directly — donated in place, no gather/scatter at all
         full = rows == list(range(self.batch))
-        g = r if full else next(s for s in self._padded_group_sizes()
-                                if s >= r)
-        pad_rows = rows + [rows[0]] * (g - r)
-        active = np.zeros((g,), bool)
-        active[:r] = True
-        admit_mask = self._admit_mask[pad_rows].copy()
-        admit_mask[r:] = False           # pads never reset the real row
-        admit_len = np.asarray(self._admit_len[pad_rows], np.int32)
-        admit_pending = np.asarray(self._admit_pending[pad_rows], np.int32)
-        step_fn = self._compiled_group_step(strategy, g)
+        with obs.span("ssv.step.prepare"):
+            g = r if full else next(s for s in self._padded_group_sizes()
+                                    if s >= r)
+            pad_rows = rows + [rows[0]] * (g - r)
+            active = np.zeros((g,), bool)
+            active[:r] = True
+            admit_mask = self._admit_mask[pad_rows].copy()
+            admit_mask[r:] = False       # pads never reset the real row
+            tail = ([jnp.asarray(self.pages[pad_rows])]
+                    if self.store.is_paged else [])
+            tail += [jnp.asarray(self.pending[pad_rows]), jnp.asarray(active),
+                     jnp.asarray(admit_mask),
+                     jnp.asarray(self._admit_len[pad_rows], jnp.int32),
+                     jnp.asarray(self._admit_pending[pad_rows], jnp.int32)]
+            self._admit_mask[rows] = False   # consumed by this launch
+            if self.serve.temperature != 0.0:
+                topo = build_topology(strategy.tree_depth, strategy.tree_width,
+                                      strategy.traversal, strategy.tree_budget)
+                us = [accept_lib.draw_uniforms(topo, self.rng)
+                      for _ in range(g)]
+                tail.append(jnp.asarray(np.stack([u for u, _ in us]),
+                                        jnp.float32))
+                tail.append(jnp.asarray([b for _, b in us], jnp.float32))
+            step_fn = self._compiled_group_step(strategy, g)
         if full:
             t_grp, d_grp = self.t_segs, self.d_segs
             t_len_in, d_len_in = self.t_len, self.d_len
         else:
-            idx = jnp.asarray(np.asarray(pad_rows, np.int32))
-            t_grp = gather_group_segments(self.t_segs, idx, self.store)
-            d_grp = gather_group_segments(self.d_segs, idx, self.store)
-            t_len_in = jnp.take(self.t_len, idx)
-            d_len_in = jnp.take(self.d_len, idx)
-        args = [self.tp, self.dp, t_grp, t_len_in, d_grp, d_len_in]
-        if self.store.is_paged:
-            args.append(jnp.asarray(self.pages[pad_rows]))
-        args += [jnp.asarray(self.pending[pad_rows]), jnp.asarray(active),
-                 jnp.asarray(admit_mask), jnp.asarray(admit_len),
-                 jnp.asarray(admit_pending)]
-        self._admit_mask[rows] = False   # consumed by this launch
-        if self.serve.temperature != 0.0:
-            topo = build_topology(strategy.tree_depth, strategy.tree_width,
-                                  strategy.traversal, strategy.tree_budget)
-            us = [accept_lib.draw_uniforms(topo, self.rng) for _ in range(g)]
-            args.append(jnp.asarray(np.stack([u for u, _ in us]), jnp.float32))
-            args.append(jnp.asarray([b for _, b in us], jnp.float32))
-        (t_grp, t_len_g, d_grp, d_len_g, out_tokens, n_acc) = step_fn(*args)
+            with obs.span("ssv.group.gather"):
+                idx = jnp.asarray(np.asarray(pad_rows, np.int32))
+                t_grp = gather_group_segments(self.t_segs, idx, self.store)
+                d_grp = gather_group_segments(self.d_segs, idx, self.store)
+                t_len_in = jnp.take(self.t_len, idx)
+                d_len_in = jnp.take(self.d_len, idx)
+        with obs.span("ssv.step.launch"):
+            (t_grp, t_len_g, d_grp, d_len_g, out_tokens,
+             n_acc) = step_fn(self.tp, self.dp, t_grp, t_len_in, d_grp,
+                              d_len_in, *tail)
         if full:
             self.t_segs, self.d_segs = t_grp, d_grp
             self.t_len, self.d_len = t_len_g, d_len_g
         else:
-            ridx = jnp.asarray(np.asarray(rows, np.int32))
-            self.t_segs = scatter_group_segments(self.t_segs, t_grp, ridx, r,
-                                                 self.store)
-            self.d_segs = scatter_group_segments(self.d_segs, d_grp, ridx, r,
-                                                 self.store)
-            self.t_len = self.t_len.at[ridx].set(t_len_g[:r])
-            self.d_len = self.d_len.at[ridx].set(d_len_g[:r])
-        toks_np = np.asarray(out_tokens)[:r]
-        n_np = np.asarray(n_acc)[:r]
-        self.pending[rows] = toks_np[np.arange(r), n_np].astype(np.int32)
-        self.committed_len[rows] = self.committed_len[rows] + n_np + 1
+            with obs.span("ssv.group.scatter"):
+                ridx = jnp.asarray(np.asarray(rows, np.int32))
+                self.t_segs = scatter_group_segments(self.t_segs, t_grp, ridx,
+                                                     r, self.store)
+                self.d_segs = scatter_group_segments(self.d_segs, d_grp, ridx,
+                                                     r, self.store)
+                self.t_len = self.t_len.at[ridx].set(t_len_g[:r])
+                self.d_len = self.d_len.at[ridx].set(d_len_g[:r])
+        with obs.span("ssv.step.sync"):
+            toks_np = np.asarray(out_tokens)[:r]
+            n_np = np.asarray(n_acc)[:r]
+        with obs.span("ssv.step.update"):
+            self.pending[rows] = toks_np[np.arange(r), n_np].astype(np.int32)
+            self.committed_len[rows] = self.committed_len[rows] + n_np + 1
         return toks_np, n_np
 
     # -------------------------------------------------------------- generate
@@ -1273,7 +1328,7 @@ class BatchedSSVEngine:
         stop_margin = self._step_headroom()
         clock = 0.0
         n_steps = 0
-        t_start = time.time()
+        t_start = obs.now_ns()
         budget = sum((r.max_new_tokens or max_new_default) for r in reqs)
         safety = 4 * budget + 16 * len(reqs) + 16
 
@@ -1282,29 +1337,32 @@ class BatchedSSVEngine:
             and finish/release the slot at eos / budget / context bound.
             Shared verbatim by the single-launch and bucketed paths."""
             req = sched.request_at(slot)
-            out = outs[req.req_id]
-            limit = req.max_new_tokens or max_new_default
-            step_logs[req.req_id].append(StepStats(
-                accepted=n, emitted=n + 1, latency_s=dt, gamma=gamma,
-                strategy=ssv, host_elems=len(toks_row) + 1))
-            finished = False
-            for t in toks_row[: n + 1]:
-                out.append(int(t))
-                if int(t) == eos_id or len(out) >= limit:
+            with obs.span("ssv.serve.harvest", req_id=req.req_id):
+                out = outs[req.req_id]
+                limit = req.max_new_tokens or max_new_default
+                step_logs[req.req_id].append(StepStats(
+                    accepted=n, emitted=n + 1, latency_s=dt, gamma=gamma,
+                    strategy=ssv, host_elems=len(toks_row) + 1))
+                finished = False
+                for t in toks_row[: n + 1]:
+                    out.append(int(t))
+                    if int(t) == eos_id or len(out) >= limit:
+                        finished = True
+                        break
+                if (self.committed_len[slot] + stop_margin
+                        >= self.serve.max_context):
                     finished = True
-                    break
-            if self.committed_len[slot] + stop_margin >= self.serve.max_context:
-                finished = True
-            if finished:
-                sched.finish(slot, now=clock + 1.0)
-                if self.store.is_paged:
-                    self._free_slot_pages(slot)   # pages return to pool
-                sched.release(slot)
+                if finished:
+                    sched.finish(slot, now=clock + 1.0)
+                    if self.store.is_paged:
+                        self._free_slot_pages(slot)   # pages return to pool
+                    sched.release(slot)
 
         while not sched.idle():
             for slot, req in sched.admit(clock):
-                self.admit(slot, req.prompt,
-                           max_new_tokens=req.max_new_tokens or max_new_default)
+                with obs.span("ssv.serve.admit", req_id=req.req_id):
+                    self.admit(slot, req.prompt, max_new_tokens=(
+                        req.max_new_tokens or max_new_default))
                 sched.mark_decoding(slot)
             active = sched.decoding_mask()
             if not active.any():
@@ -1359,7 +1417,7 @@ class BatchedSSVEngine:
             n_steps += 1
             if n_steps > safety:   # shapes guarantee progress; belt-and-braces
                 break
-        wall = time.time() - t_start
+        wall = (obs.now_ns() - t_start) / 1e9
         results = [GenerationResult(tokens=np.asarray(outs[r.req_id]),
                                     steps=step_logs[r.req_id]) for r in reqs]
         # mean decoding-slot fraction per bucket over the stepped rounds

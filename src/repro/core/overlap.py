@@ -20,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
+
 SENTINEL = jnp.int32(2 ** 30)
 
 
@@ -83,7 +85,9 @@ def group_queries(T: int, C: int):
     Memoized by (T, C): the layout map is pure host-side numpy and was being
     rebuilt on every fused-verify call (`kernels/nsa_verify/ops.prepare_groups`
     invokes it once per layer per step). The cached array is marked
-    read-only so call sites cannot mutate the shared copy."""
+    read-only so call sites cannot mutate the shared copy. Call sites
+    count their lookups, and this body its builds, into ``obs``."""
+    obs.count("kernel.group_layout.builds")
     ngroups = pad_to_groups(T, C)
     pad = ngroups * C - T
     qidx = np.concatenate([np.arange(T), np.full(pad, T - 1)])      # clamp pad
@@ -104,6 +108,7 @@ def merged_schedule(sel_idx, sel_valid, C: int):
     identical to independent per-query execution.
     """
     B, T, H, n = sel_idx.shape
+    obs.count("kernel.group_layout.lookups")
     qmap, pad = group_queries(T, C)
     G = qmap.shape[0]
     gi = jnp.asarray(qmap)                                           # (G, C)
@@ -149,6 +154,7 @@ def shared_index(sel_idx, sel_valid, positions, C: int):
     verification is oblivious to the grouping mode.
     """
     B, T, H, n = sel_idx.shape
+    obs.count("kernel.group_layout.lookups")
     qmap, pad = group_queries(T, C)
     G = qmap.shape[0]
     gi = jnp.asarray(qmap)                                           # (G, C)

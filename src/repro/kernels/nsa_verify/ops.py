@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import NSAConfig
 from repro.core import kvstore, overlap
 from repro.kernels.nsa_verify import kernel as K
@@ -42,10 +43,11 @@ def _pad_axis(x, axis: int, target: int):
 # execution group's (strategy, group-size) pair contributes its own (T, C, M,
 # ...) tuple per layer mode, so the seed maxsize of 128 could thrash once a
 # profile's worth of strategies serve concurrently. 1024 entries keep every
-# realistic shape set resident; hit/miss counters are surfaced through
-# ``verify_call_cache_info`` into the engines' kernel-cache metrics.
+# realistic shape set resident. Lookups (at the call site) and builds count
+# into ``obs``, from which the engines' kernel-cache metrics read them.
 @functools.lru_cache(maxsize=1024)
 def _cached_call(key):
+    obs.count("kernel.verify_call.builds")
     return K.build_verify_call(**dict(key))
 
 
@@ -66,6 +68,7 @@ def prepare_groups(q, gates, sel_idx, sel_valid, positions, C: int, mode: str,
     B, T, Hq, Dh = q.shape
     Hkv = sel_idx.shape[2]
     Gq = Hq // Hkv
+    obs.count("kernel.group_layout.lookups")
     qmap, _ = overlap.group_queries(T, C)
     G = qmap.shape[0]
     gi = jnp.asarray(qmap)                                          # (G, C)
@@ -212,6 +215,7 @@ def nsa_verify_fused(q, k_cache, v_cache, k_cmp, v_cmp, k_draft, v_draft,
         interpret=resolve_interpret(interpret),
         paged=paged, blocks_per_page=(ps // lb if paged else 1),
         max_pages=(page_table.shape[1] if paged else 0)).items()))
+    obs.count("kernel.verify_call.lookups")
     call = _cached_call(key)
 
     merged_c = jnp.clip(merged, 0, nsb_logical - 1)

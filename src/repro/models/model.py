@@ -368,6 +368,7 @@ def _mix_verify(bp, cfg: ModelConfig, kind: str, h, cache, prefix_len, positions
         return outs, {"state_buf": buf}, carry_idx
     kv = kvstore.as_view(cache["kv"], pages)
     if cfg.attention == "nsa":
+        @jax.named_scope("nsa.select")
         def fresh(_):
             q, _, _ = attention.qkv(bp["mix"], cfg, h, positions)
             _, p_slc = nsa_lib.routing(bp["mix"], cfg, q, cache["cmp"]["k_cmp"],

@@ -414,53 +414,57 @@ def nsa_verify_ref(params, cfg: ModelConfig, x, cache, cmp_cache, prefix_len,
     # ---- routing + cmp branch over committed prefix (max shapes + validity:
     # prefix_len may be a traced scalar in the jitted serve path)
     k_cmp, v_cmp = cmp_cache["k_cmp"], cmp_cache["v_cmp"]
-    o_cmp, p_slc = routing(params, cfg, q, k_cmp, v_cmp, positions,
-                           kv_len=kv.max_len, ncb_valid=ncb_valid)
+    with jax.named_scope("nsa.cmp"):
+        o_cmp, p_slc = routing(params, cfg, q, k_cmp, v_cmp, positions,
+                               kv_len=kv.max_len, ncb_valid=ncb_valid)
     if sel_idx is None:
-        sel_idx, sel_valid = select_topn(p_slc, positions, prefix_len, nsa)
+        with jax.named_scope("nsa.select"):
+            sel_idx, sel_valid = select_topn(p_slc, positions, prefix_len, nsa)
 
     # ---- slc branch: gather + per-token causal/prefix mask
-    k_sel, v_sel = gather_blocks(kv, sel_idx, nsa.sel_block)
-    n = sel_idx.shape[-1]
-    tok_pos = sel_idx[..., None] * nsa.sel_block + jnp.arange(nsa.sel_block)  # (B,T,Hkv,n,l')
     qg = q.reshape(B, T, Hkv, G, Dh)
-    logit_sel = jnp.einsum("bthgd,bthnld->bthgnl", qg.astype(jnp.float32),
-                           k_sel.astype(jnp.float32)) * scale
-    # tok_pos >= 0 guards adversarial negative block indices (which would
-    # otherwise pass the prefix/causal checks against a zero-filled gather)
-    m_sel = (tok_pos >= 0) & (tok_pos < prefix_len) & \
-        (tok_pos <= positions[:, :, None, None, None]) & sel_valid[..., None]
-    logit_sel = jnp.where(m_sel[:, :, :, None], logit_sel, NEG_INF)
-    flat = logit_sel.reshape(B, T, Hkv, G, n * nsa.sel_block)
-    p_sel = jax.nn.softmax(flat, axis=-1)
-    p_sel = jnp.where(m_sel[:, :, :, None].reshape(B, T, Hkv, 1, -1), p_sel, 0.0)
-    o_slc = jnp.einsum("bthgk,bthkd->bthgd", p_sel,
-                       v_sel.reshape(B, T, Hkv, n * nsa.sel_block, Dh).astype(jnp.float32))
-    o_slc = o_slc.reshape(B, T, Hq, Dh)
+    with jax.named_scope("nsa.slc"):
+        k_sel, v_sel = gather_blocks(kv, sel_idx, nsa.sel_block)
+        n = sel_idx.shape[-1]
+        tok_pos = sel_idx[..., None] * nsa.sel_block + jnp.arange(nsa.sel_block)  # (B,T,Hkv,n,l')
+        logit_sel = jnp.einsum("bthgd,bthnld->bthgnl", qg.astype(jnp.float32),
+                               k_sel.astype(jnp.float32)) * scale
+        # tok_pos >= 0 guards adversarial negative block indices (which would
+        # otherwise pass the prefix/causal checks against a zero-filled gather)
+        m_sel = (tok_pos >= 0) & (tok_pos < prefix_len) & \
+            (tok_pos <= positions[:, :, None, None, None]) & sel_valid[..., None]
+        logit_sel = jnp.where(m_sel[:, :, :, None], logit_sel, NEG_INF)
+        flat = logit_sel.reshape(B, T, Hkv, G, n * nsa.sel_block)
+        p_sel = jax.nn.softmax(flat, axis=-1)
+        p_sel = jnp.where(m_sel[:, :, :, None].reshape(B, T, Hkv, 1, -1), p_sel, 0.0)
+        o_slc = jnp.einsum("bthgk,bthkd->bthgd", p_sel,
+                           v_sel.reshape(B, T, Hkv, n * nsa.sel_block, Dh).astype(jnp.float32))
+        o_slc = o_slc.reshape(B, T, Hq, Dh)
 
     # ---- win branch: trailing-window *slice* of the prefix (keeps decode
     # sub-quadratic at 500K context) + tree-masked draft tokens
-    S_max = kv.max_len
-    W = min(nsa.window, S_max)
-    win_start = jnp.clip(jnp.asarray(prefix_len) - W, 0, max(S_max - W, 0))
-    k_win, v_win = kv.window(win_start, W)
-    kpos = jnp.broadcast_to((win_start + jnp.arange(W)).reshape(1, 1, W), (B, T, W))
-    pmask = (kpos < jnp.asarray(prefix_len)) & \
-        (kpos > positions[..., None] - nsa.window) & (kpos <= positions[..., None])
-    logit_p = jnp.einsum("bthgd,bkhd->bthgk", qg.astype(jnp.float32),
-                         k_win.astype(jnp.float32)) * scale
-    logit_p = jnp.where(pmask[:, :, None, None], logit_p, NEG_INF)
-    dist = positions[:, :, None] - positions[:, None, :]
-    dmask = tree_mask & (dist < nsa.window) & (dist >= 0)
-    logit_d = jnp.einsum("bthgd,bkhd->bthgk", qg.astype(jnp.float32),
-                         k_new.astype(jnp.float32)) * scale
-    logit_d = jnp.where(dmask[:, :, None, None], logit_d, NEG_INF)
-    logit_w = jnp.concatenate([logit_p, logit_d], axis=-1)
-    p_w = jax.nn.softmax(logit_w, axis=-1)
-    o_win = jnp.einsum("bthgk,bkhd->bthgd", p_w[..., :W],
-                       v_win.astype(jnp.float32)) + \
-        jnp.einsum("bthgk,bkhd->bthgd", p_w[..., W:], v_new.astype(jnp.float32))
-    o_win = o_win.reshape(B, T, Hq, Dh)
+    with jax.named_scope("nsa.win"):
+        S_max = kv.max_len
+        W = min(nsa.window, S_max)
+        win_start = jnp.clip(jnp.asarray(prefix_len) - W, 0, max(S_max - W, 0))
+        k_win, v_win = kv.window(win_start, W)
+        kpos = jnp.broadcast_to((win_start + jnp.arange(W)).reshape(1, 1, W), (B, T, W))
+        pmask = (kpos < jnp.asarray(prefix_len)) & \
+            (kpos > positions[..., None] - nsa.window) & (kpos <= positions[..., None])
+        logit_p = jnp.einsum("bthgd,bkhd->bthgk", qg.astype(jnp.float32),
+                             k_win.astype(jnp.float32)) * scale
+        logit_p = jnp.where(pmask[:, :, None, None], logit_p, NEG_INF)
+        dist = positions[:, :, None] - positions[:, None, :]
+        dmask = tree_mask & (dist < nsa.window) & (dist >= 0)
+        logit_d = jnp.einsum("bthgd,bkhd->bthgk", qg.astype(jnp.float32),
+                             k_new.astype(jnp.float32)) * scale
+        logit_d = jnp.where(dmask[:, :, None, None], logit_d, NEG_INF)
+        logit_w = jnp.concatenate([logit_p, logit_d], axis=-1)
+        p_w = jax.nn.softmax(logit_w, axis=-1)
+        o_win = jnp.einsum("bthgk,bkhd->bthgd", p_w[..., :W],
+                           v_win.astype(jnp.float32)) + \
+            jnp.einsum("bthgk,bkhd->bthgd", p_w[..., W:], v_new.astype(jnp.float32))
+        o_win = o_win.reshape(B, T, Hq, Dh)
 
     out = (g_all[:, :, 0, :, None] * o_cmp + g_all[:, :, 1, :, None] * o_slc +
            g_all[:, :, 2, :, None] * o_win).astype(x.dtype)
