@@ -17,10 +17,11 @@ Two backends hide behind one interface:
 The page size is aligned with the NSA selection-block granularity
 (``page_size % sel_block == 0``, default ``page_size == sel_block``): a
 selected block index resolves to a page-table entry, turning the paper's
-sparse selected-KV gather into natively paged access. Out-of-range or
-unmapped lookups read an explicit zero page (never a silently clamped
-neighbor) and writes to them are dropped — the adversarial-index contract
-``tests/test_kvstore.py`` pins down.
+sparse selected-KV gather into natively paged access: one KV head's block is
+fetched as one ``(sel_block, Dh)`` slab of its page with a single gather
+index. Out-of-range or unmapped lookups read an explicit zero page (never a
+silently clamped neighbor) and writes to them are dropped — the
+adversarial-index contract ``tests/test_kvstore.py`` pins down.
 
 Device-side state is a plain pytree (`KVView` wraps the per-layer K/V
 storage plus the shared page table); host-side page accounting is the
@@ -120,18 +121,23 @@ class KVView:
         return self.pages.shape[0] if self.is_paged else self.k.shape[0]
 
     # ---- paged address resolution
+    def _phys_page(self, lp):
+        """lp (B, ...) logical page indices -> (physical page, valid). Invalid
+        (negative / past the table / unmapped) pages resolve to page 0 with
+        ``valid`` False: callers read an explicit zero or drop the write."""
+        B, MP = self.pages.shape
+        phys = jnp.take_along_axis(self.pages,
+                                   jnp.clip(lp, 0, MP - 1).reshape(B, -1),
+                                   axis=1).reshape(lp.shape)
+        ok = (lp >= 0) & (lp < MP) & (phys >= 0)
+        return jnp.where(ok, phys, 0), ok
+
     def _phys_flat(self, tok):
         """tok (B, ...) absolute positions -> flat pool-token index, -1 for
         out-of-range / unmapped (explicit zero page downstream)."""
         ps = self.page_size
-        B = self.pages.shape[0]
-        MP = self.pages.shape[1]
-        valid = (tok >= 0) & (tok < MP * ps)
-        lp = jnp.clip(tok // ps, 0, MP - 1)
-        phys = jnp.take_along_axis(self.pages, lp.reshape(B, -1),
-                                   axis=1).reshape(lp.shape)
-        flat = phys * ps + tok % ps
-        return jnp.where(valid & (phys >= 0), flat, -1)
+        phys, ok = self._phys_page(tok // ps)
+        return jnp.where(ok, phys * ps + tok % ps, -1)
 
     # ---- reads
     def gather_tokens(self, tok):
@@ -157,22 +163,27 @@ class KVView:
         """Selected-block gather (head-aligned): idx (B, T, Hkv, n) block
         indices -> k/v (B, T, Hkv, n, sel_block, Dh).
 
-        Paged: a block index is a page-table lookup (pages tile sel blocks).
-        Invalid / out-of-range / unmapped blocks read an explicit zero page —
-        never a clamped neighbor (see tests/test_kvstore.py adversarial sel).
+        Paged: a block index is a page-table lookup (pages tile sel blocks),
+        resolved once per (row, node, head, block), which then fetches one
+        ``(sel_block, Dh)`` slab of its page — not ``sel_block`` rows of one
+        token each. Invalid / out-of-range / unmapped blocks read an explicit
+        zero page — never a clamped neighbor (see tests/test_kvstore.py
+        adversarial sel).
         """
         B, T, Hkv, n = idx.shape
-        tok = idx[..., None] * sel_block + jnp.arange(sel_block)  # (B,T,Hkv,n,l')
         if self.is_paged:
-            flat = self._phys_flat(tok)
-            P, ps = self.k.shape[0], self.page_size
-            kf = self.k.reshape(P * ps, *self.k.shape[2:])       # (P*ps, Hkv, Dh)
-            vf = self.v.reshape(P * ps, *self.v.shape[2:])
-            ok = (flat >= 0)[..., None]
-            fidx = jnp.clip(flat, 0, P * ps - 1)
-            hidx = jnp.arange(Hkv).reshape(1, 1, Hkv, 1, 1)
-            return (jnp.where(ok, kf[fidx, hidx], 0),
-                    jnp.where(ok, vf[fidx, hidx], 0))
+            m = self.page_size // sel_block                  # blocks per page
+            phys, ok = self._phys_page(idx // m)
+            sub = idx % m
+            hidx = jnp.arange(Hkv).reshape(1, 1, Hkv, 1)
+            ok = ok[..., None, None]
+
+            def slabs(pool):       # (P, m, sel_block, Hkv, Dh) -> one slab each
+                blocks = pool.reshape((pool.shape[0], m, sel_block)
+                                      + pool.shape[2:])
+                return jnp.where(ok, blocks[phys, sub, :, hidx], 0)
+            return slabs(self.k), slabs(self.v)
+        tok = idx[..., None] * sel_block + jnp.arange(sel_block)  # (B,T,Hkv,n,l')
         S = self.k.shape[1]
         ok = ((tok >= 0) & (tok < S))[..., None]
         tokc = jnp.clip(tok, 0, S - 1)

@@ -122,6 +122,25 @@ def _paged_twin(rng, B=2, S=64, H=2, D=8, ps=16, extra_pages=3, perm_seed=0):
             KS.KVView(poolk, poolv, jnp.asarray(pages)))
 
 
+def _logical_blocks(dense, idx, sel_block):
+    """Plain-loop selected-block gather on the dense logical cache: idx
+    (B, T, H, n) -> (B, T, H, n, sel_block, D); a block wholly or partly
+    outside [0, S) reads zeros where it leaves the cache."""
+    k, v = np.asarray(dense.k), np.asarray(dense.v)
+    S, D = k.shape[1], k.shape[3]
+    idx = np.asarray(idx)
+    out_k = np.zeros(idx.shape + (sel_block, D), k.dtype)
+    out_v = np.zeros_like(out_k)
+    for i in np.ndindex(*idx.shape):
+        b, h = i[0], i[2]
+        for j in range(sel_block):
+            t = idx[i] * sel_block + j
+            if 0 <= t < S:
+                out_k[i + (j,)] = k[b, t, h]
+                out_v[i + (j,)] = v[b, t, h]
+    return out_k, out_v
+
+
 @seeded_property(n_examples=10)
 def test_view_read_paths_match_dense(seed):
     rng = np.random.default_rng(seed)
@@ -165,6 +184,64 @@ def test_view_writes_match_dense_and_respect_masks(rng):
     pk3, _ = paged.write(kn, vn, jnp.full((2,), paged.max_len - 2),
                          row_mask=jnp.array([True, True]))
     assert np.asarray(pk3).shape == before.shape   # no error, partial drop
+
+
+@pytest.mark.parametrize("case", ["page_is_block", "page_is_two_blocks",
+                                  "unmapped_pages", "negative_blocks",
+                                  "past_the_end_blocks"])
+def test_gather_blocks_slabs_match_logical_gather(case):
+    """The paged slab gather returns exactly what a plain gather of the
+    dense logical cache returns, for pages of one or two selection blocks;
+    unmapped pages and negative / past-the-end block indices read zeros."""
+    rng = np.random.default_rng(3)
+    sel_block = 8
+    ps = 2 * sel_block if case == "page_is_two_blocks" else sel_block
+    dense, paged = _paged_twin(rng, S=64, H=3, D=8, ps=ps, perm_seed=4)
+    nsb = dense.max_len // sel_block
+    lo, hi = {"negative_blocks": (-nsb, 0),
+              "past_the_end_blocks": (nsb, 2 * nsb)}.get(case, (0, nsb))
+    idx = jnp.asarray(rng.integers(lo, hi, size=(2, 5, 3, 4)), jnp.int32)
+    if case == "unmapped_pages":
+        # the first and last logical page of row 0 unmapped: the slab gather
+        # reads zeros where the dense cache still holds the bytes
+        holey = paged.pages.at[0, 0].set(-1).at[0, -1].set(-1)
+        paged = KS.KVView(paged.k, paged.v, holey)
+        want_k, want_v = _logical_blocks(dense, idx, sel_block)
+        lp = np.asarray(idx)[0] // (ps // sel_block)
+        hole = (lp == 0) | (lp == 64 // ps - 1)
+        want_k[0][hole] = 0.0
+        want_v[0][hole] = 0.0
+    else:
+        want_k, want_v = _logical_blocks(dense, idx, sel_block)
+    got_k, got_v = paged.gather_blocks(idx, sel_block)
+    assert got_k.shape == want_k.shape
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    np.testing.assert_array_equal(np.asarray(got_v), want_v)
+    if case in ("negative_blocks", "past_the_end_blocks"):
+        assert not np.asarray(got_k).any()
+
+
+@pytest.mark.parametrize("ps", [8, 16])
+def test_paged_write_then_gather_tokens_round_trip(ps):
+    """A paged write lands each token at pool[page, offset] of its row's
+    page and gather_tokens reads the same values back."""
+    rng = np.random.default_rng(10 + ps)
+    _, paged = _paged_twin(rng, S=64, H=3, D=8, ps=ps, perm_seed=1)
+    kn = jnp.asarray(rng.normal(size=(2, 11, 3, 8)).astype(np.float32))
+    vn = jnp.asarray(rng.normal(size=(2, 11, 3, 8)).astype(np.float32))
+    start = jnp.asarray([ps - 3, 2 * ps + 1], jnp.int32)
+    pk, pv = paged.write(kn, vn, start, row_mask=jnp.array([True, True]))
+    pages = np.asarray(paged.pages)
+    for b in range(2):
+        for t in range(11):
+            pos = int(start[b]) + t
+            np.testing.assert_array_equal(
+                np.asarray(pk)[pages[b, pos // ps], pos % ps],
+                np.asarray(kn)[b, t])
+    tok = start[:, None] + jnp.arange(11)
+    back = KS.KVView(pk, pv, paged.pages).gather_tokens(tok)
+    np.testing.assert_array_equal(np.asarray(back[0]), np.asarray(kn))
+    np.testing.assert_array_equal(np.asarray(back[1]), np.asarray(vn))
 
 
 # ------------------------------------------------ adversarial selected blocks
