@@ -42,6 +42,13 @@ MODEL_FIELDS = {
     "torch_dtype": "dtype", "attention": "attention",
 }
 ACTIVATIONS = {"silu": "swiglu", "gelu": "geglu"}
+# layer kinds a configuration file may name in ``block_pattern``
+KINDS = ("attn", "moe")
+# the source's own names for experts: at the top level of a file they are a
+# record of the published model, and the program reads its experts only
+# from the ``moe`` group, so stating them with no expert layer is an error
+SOURCE_EXPERT_KEYS = ("num_experts", "num_local_experts", "n_routed_experts",
+                      "num_experts_per_tok", "moe_intermediate_size")
 
 
 # ---------------------------------------------------------------- lookup
@@ -80,13 +87,46 @@ def load_peaks(device_kind: str) -> dict:
 
 
 # ------------------------------------------------------- system under test
-def model_config(c: dict, *, draft: bool = False):
-    """The program's ModelConfig for a configuration file (or its draft)."""
-    from repro.config import ModelConfig, NSAConfig
+def _group(cls, group: dict, key: str):
+    """``cls(**group)``, with a key the program does not know an error."""
+    fields = {f.name for f in dataclasses.fields(cls)}
+    unknown = sorted(set(group) - fields)
+    if unknown:
+        raise ValueError(f"{key}: {unknown} are not fields of the program's "
+                         f"{cls.__name__} ({sorted(fields)})")
+    return cls(**group)
+
+
+def model_config(c: dict):
+    """The program's ModelConfig for a configuration file.
+
+    An optional ``block_pattern`` (kinds from ``KINDS``) is tiled over the
+    layers by the program; an optional ``moe`` group is the program's
+    ``MoEConfig`` field by field, and goes with a ``"moe"`` kind: one
+    without the other is an error. In that group ``num_experts`` is the
+    experts whose weights this chip holds (listed in ``reduced``) and
+    ``router_experts`` the router's published width, ``num_experts`` where
+    absent."""
+    from repro.config import ModelConfig, MoEConfig, NSAConfig
     kw = {MODEL_FIELDS[k]: v for k, v in c.items() if k in MODEL_FIELDS}
     kw["activation"] = ACTIVATIONS[c.get("hidden_act", "silu")]
-    kw["nsa"] = NSAConfig(**c["nsa"])
-    kw["name"] = c["name"] + ("-draft" if draft else "")
+    kw["nsa"] = _group(NSAConfig, c["nsa"], "nsa")
+    kw["name"] = c["name"]
+    pattern = tuple(c.get("block_pattern", ("attn",)))
+    bad = [k for k in pattern if k not in KINDS]
+    if not pattern or bad:
+        raise ValueError(f"block_pattern {list(pattern)}: kinds must be from {KINDS}")
+    if ("moe" in pattern) != ("moe" in c):
+        raise ValueError("a 'moe' kind in block_pattern and a moe group go "
+                         "together; the file has only one of them")
+    stated = [k for k in SOURCE_EXPERT_KEYS if k in c]
+    if stated and "moe" not in pattern:
+        raise ValueError(f"{stated} state experts but no layer is 'moe': the "
+                         "program would run a dense FFN")
+    if "block_pattern" in c:
+        kw["block_pattern"] = pattern
+    if "moe" in c:
+        kw["moe"] = _group(MoEConfig, c["moe"], "moe")
     return ModelConfig(**kw)
 
 
@@ -106,6 +146,24 @@ def draft_model_config(c: dict, tcfg):
             raise ValueError(f"draft {k}: the program builds {v}, "
                              f"{c['name']}.json states {d[k]}")
     return dcfg
+
+
+def step_hlo(eng, clock: "CompileClock") -> str:
+    """The optimized HLO text of the fused step that the serving loop runs
+    (greedy, at the engine's slot count), from the executable that set-up
+    compiled or loaded; "" where reading it made ``clock`` see a backend
+    compile, since the text is then of a program that did not run.
+    ``bench/trace.py`` also holds the text to the ops the trace ran."""
+    from repro.core import engine as engine_lib
+    fn = engine_lib.jit_batched_step(eng.tcfg, eng.dcfg, eng.serve.ssv, True,
+                                     0.0, eng.store)
+    ivec = np.zeros((eng.batch,), np.int32)
+    bvec = np.zeros((eng.batch,), bool)
+    t0 = clock.now()
+    text = fn.lower(eng.tp, eng.dp, eng.t_segs, eng.t_len, eng.d_segs,
+                    eng.d_len, eng.pages, ivec, bvec, bvec, ivec,
+                    ivec).compile().as_text()
+    return "" if clock.compiles_between(t0, clock.now()) else text
 
 
 def strategy(c: dict):

@@ -1,0 +1,8 @@
+"""draft_device_ms: device ms per fused step under the named scope
+``ssv.draft`` (the draft's tree expansion, its ``ssv.draft.topk`` included),
+in the traced stretch (``bench/trace.py``'s ``scope_s`` over its steps)."""
+from bench import trace
+
+
+def read(run):
+    return trace.scope_ms_per_step(run.trace, "ssv.draft")

@@ -120,6 +120,7 @@ def run_cell(w: dict, cfg: dict, mix: dict, e2e: list, per_layer: list,
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
               "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+    hlo = harness.step_hlo(eng, clock) if trace else ""
     # free the program's state before the reference runs
     eng.t_segs = eng.d_segs = None
     del eng, drv.eng
@@ -144,9 +145,13 @@ def run_cell(w: dict, cfg: dict, mix: dict, e2e: list, per_layer: list,
         out["failed"] = sum(1 for r in held if r.first_token is None)
     out["attempted"] = len(held)
     if trace and drv.profile and len(drv.profile) == 2:
-        rec.trace = trace_lib.reduce_dir(trace_dir)
+        rec.trace = trace_lib.reduce_dir(trace_dir, hlo)
         shutil.rmtree(trace_dir, ignore_errors=True)
         if rec.trace:
+            print(f"trace: the step's HLO ({len(hlo)} chars) matches "
+                  f"{rec.trace['hlo_match']!r} of its module's device time, "
+                  f"{rec.trace['hlo_programs']} program(s) of that name",
+                  file=sys.stderr)
             device["busy_s"] = rec.trace["busy_s"]
             device["window_s"] = rec.trace["window_s"]
     metrics = {}

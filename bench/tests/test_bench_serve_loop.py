@@ -38,9 +38,10 @@ def test_open_loop_cell_traced(tmp_path):
     out = drive(tiny.OPEN, "nsa1b.decode16k", trace=True, trace_dir=tmp_path / "t")
     assert out["correct"] is True
     m = out["metrics"]
-    # the CPU trace has no TPU plane, so the device's idle share is left
-    # out rather than read as 0
-    assert {"window_mfu", "step_mfu", "accept_len", "compiles_in_window"} == set(m)
+    # the CPU trace has no TPU plane, so the device's idle share and the
+    # phases' device time are left out rather than read as 0
+    assert {"window_mfu", "step_mfu", "accept_len", "compiles_in_window",
+            "step_host_ms", "slow_steps", "setup_compile_s"} == set(m)
     assert "device_idle_share" not in m and "busy_s" not in out["device"]
     assert m["compiles_in_window"]["value"] == 0
     assert 0 < m["step_mfu"]["value"] and 0 < m["window_mfu"]["value"]

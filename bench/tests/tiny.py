@@ -34,3 +34,11 @@ def cfg(**kw):
     c = copy.deepcopy(CFG)
     c.update(kw)
     return c
+
+
+def moe_cfg(**moe):
+    """The tiny configuration with every layer an expert layer: 4 experts
+    held, top-2, experts half the dense FFN's width."""
+    group = {"num_experts": 4, "top_k": 2, "d_expert": 64}
+    group.update(moe)
+    return cfg(name="tiny-nsa-moe", block_pattern=["moe"], moe=group)
