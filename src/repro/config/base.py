@@ -44,9 +44,18 @@ class NSAConfig:
 
 @dataclass(frozen=True)
 class MoEConfig:
-    """Mixture-of-experts FFN."""
+    """Mixture-of-experts FFN.
+
+    A layer may hold a share of a larger expert set, as one chip of an
+    expert-parallel deployment does: the router scores ``router_experts``
+    experts (0: ``num_experts``) and picks its ``top_k`` from all of them,
+    and this layer holds the ``num_experts`` experts from ``first_expert``
+    on. A token's output is its held experts' part of the routed sum.
+    """
 
     num_experts: int = 8
+    router_experts: int = 0
+    first_expert: int = 0
     top_k: int = 2
     d_expert: int = 0          # expert hidden dim (0 -> use model d_ff)
     capacity_factor: float = 1.25
@@ -55,6 +64,10 @@ class MoEConfig:
     # GShard-style dispatch group size: dispatch-einsum overhead scales as
     # group·cf/(3·d_ff), so thin-expert archs (qwen3-moe) use smaller groups.
     dispatch_group: int = 1024
+
+    @property
+    def router_width(self) -> int:
+        return self.router_experts or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -171,7 +184,7 @@ class ModelConfig:
         if moe and self.moe is not None:
             dff = self.moe.d_expert or self.d_ff
             per_exp = (3 if gated else 2) * d * dff
-            return self.moe.num_experts * per_exp + d * self.moe.num_experts + \
+            return self.moe.num_experts * per_exp + d * self.moe.router_width + \
                 self.moe.num_shared_experts * per_ffn
         return per_ffn
 

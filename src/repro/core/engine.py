@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -752,6 +753,7 @@ class BatchedSSVEngine:
         self._admit_mask: Optional[np.ndarray] = None
         self._admit_len: Optional[np.ndarray] = None
         self._admit_pending: Optional[np.ndarray] = None
+        self._compiles_at_freeze = -1
         # KV store backend: one page pool + one page table serve BOTH models
         # (same logical token positions per row; per-model pools differ only
         # in head geometry / layer count)
@@ -1072,10 +1074,25 @@ class BatchedSSVEngine:
         rows = int(live.sum())
         with obs.span("ssv.step", rows=rows):
             toks_np, n_np = self._step(live, ssv)
+        self._freeze_heap_after_compiles()
         obs.count("ssv.steps")
         obs.count("ssv.rows_stepped", rows)
         obs.count("ssv.tokens_committed", int((n_np + 1)[live].sum()))
         return toks_np, n_np
+
+    def _freeze_heap_after_compiles(self):
+        """After a step that followed a compile or a compile-cache load, move
+        every live Python object to the collector's permanent generation
+        (``gc.freeze``). Tracing and compiling leave a large heap that lives
+        as long as the jit caches; a full collection walks all of it, and
+        one that falls inside a later step's device wait holds the host, and
+        so the idle chip, until it ends. Later full collections walk only
+        what came after. Cyclic garbage alive at the freeze is never
+        collected."""
+        n = obs.backend_compiles()
+        if n != self._compiles_at_freeze:
+            self._compiles_at_freeze = n
+            gc.freeze()
 
     def _step(self, live: np.ndarray, ssv: SSVConfig):
         with obs.span("ssv.step.prepare"):
@@ -1142,6 +1159,7 @@ class BatchedSSVEngine:
                 raise ValueError(f"row {s} out of range for batch {self.batch}")
         with obs.span("ssv.step_group", rows=len(rows)):
             toks_np, n_np = self._step_group(rows, strategy)
+        self._freeze_heap_after_compiles()
         obs.count("ssv.steps")
         obs.count("ssv.rows_stepped", len(rows))
         obs.count("ssv.tokens_committed", int((n_np + 1).sum()))

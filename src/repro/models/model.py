@@ -78,9 +78,13 @@ def block_init(key, cfg: ModelConfig, kind: str, dtype):
     return p
 
 
-def _apply_ffn(bp, cfg: ModelConfig, kind: str, x):
-    """Returns (y, aux)."""
+def _apply_ffn(bp, cfg: ModelConfig, kind: str, x, serving: bool = False):
+    """Returns (y, aux). Expert layers serve through the dropless dispatch
+    (a token's output never depends on the other tokens in the call) and
+    train through the capacity dispatch."""
     if kind == "moe":
+        if serving:
+            return moe_lib.moe_apply_dropless(bp["ffn"], cfg, x)
         return moe_lib.moe_apply(bp["ffn"], cfg, x)
     if "ffn" in bp:
         return layers.ffn(bp["ffn"], x, cfg.activation), jnp.float32(0.0)
@@ -296,7 +300,7 @@ def prefill(params, cfg: ModelConfig, tokens, max_len: int, frontend=None,
                     caches_out.append({"kv": cache})
                 h = h + mix
                 hn = layers.rmsnorm(bp["norm2"], h, cfg.norm_eps)
-                y, _ = _apply_ffn(bp, cfg, kind, hn)
+                y, _ = _apply_ffn(bp, cfg, kind, hn, serving=True)
                 h = h + y
             if constrain is not None:
                 h = constrain(h)
@@ -438,7 +442,7 @@ def verify_step(params, cfg: ModelConfig, caches, draft_tokens, positions, tree_
                                             gflags[j], ssv, pages=caches.get("pages"))
                 h = h + mix
                 hn = layers.rmsnorm(gp[j]["norm2"], h, cfg.norm_eps)
-                y, _ = _apply_ffn(gp[j], cfg, kind, hn)
+                y, _ = _apply_ffn(gp[j], cfg, kind, hn, serving=True)
                 h = h + y
                 ups.append(up)
             return (h, cidx), tuple(ups)

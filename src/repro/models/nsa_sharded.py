@@ -231,7 +231,7 @@ def decode_step_sharded(params, cfg: ModelConfig, mesh, caches, tokens,
                     prefix_len, seq_axes)
                 h = h + mix
                 hn = layers.rmsnorm(bp["norm2"], h, cfg.norm_eps)
-                y, _ = model_lib._apply_ffn(bp, cfg, kind, hn)
+                y, _ = model_lib._apply_ffn(bp, cfg, kind, hn, serving=True)
                 h = h + y
                 new_cache.append({"kv": kv, "cmp": cmp})
             return h, tuple(new_cache)

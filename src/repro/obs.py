@@ -21,6 +21,8 @@ Always on, in memory, for the life of the process:
   persistent cache's lookups.
 
 ``snapshot()`` returns the three records; ``reset()`` clears them.
+``backend_compiles()`` counts the backend compiles since the process
+started, and is never reset.
 """
 from __future__ import annotations
 
@@ -66,6 +68,7 @@ _ring: list = [None] * RING
 _seq = itertools.count()
 _counters: Dict[str, int] = collections.defaultdict(int)
 _compiles: collections.deque = collections.deque(maxlen=RING)
+_backend_compiles = 0
 
 
 def _stack() -> list:
@@ -112,6 +115,10 @@ def count(name: str, n: int = 1):
 def counters() -> Dict[str, int]:
     with _lock:
         return dict(_counters)
+
+
+def backend_compiles() -> int:
+    return _backend_compiles
 
 
 def snapshot() -> dict:
@@ -166,8 +173,10 @@ def _on_duration(event, duration, fun_name=None, **_):
             duration = max(duration - load, 0.0)
         count("compiles." + name)
     recs.append(Compile(name, phase, float(duration), end))
+    global _backend_compiles
     with _lock:
         _compiles.extend(recs)
+        _backend_compiles += phase == "compile"
 
 
 def _on_event(event, **_):
