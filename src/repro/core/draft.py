@@ -7,9 +7,11 @@ pending root is augmented with the target's last hidden state (projected),
 which is how EAGLE-3 conditions the draft on target features.
 
 Tree expansion runs level by level: level-(d+1) candidate tokens are the
-top-k of the draft's logits at the depth-d nodes. Each level re-verifies the
-partial tree through the draft's own ``verify_step`` (tree-masked), so the
-draft KV used for deeper levels is exact. The final full-tree pass also
+top-k of the draft's logits at the depth-d nodes, taken from those parent
+rows alone by k max-reductions (``top_k_indices``), not by sorting the
+vocabulary. Each level re-verifies the partial tree through the draft's own
+``verify_step`` (tree-masked), so the draft KV used for deeper levels is
+exact. The final full-tree pass also
 yields the draft-side K/V updates used to commit accepted tokens into the
 draft cache, and the per-node draft distributions ``node_q`` consumed by
 stochastic acceptance.
@@ -23,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.config import ModelConfig
 from repro.core.tree import TreeTopology, positions_for
 from repro.models import layers, model
@@ -64,6 +67,35 @@ def sibling_ranks(topo: TreeTopology) -> np.ndarray:
     return rank
 
 
+def _total_order_key(x):
+    """int32 keys ordered as ``lax.top_k`` orders floats (IEEE total order:
+    -NaN < -inf < ... < -0 < +0 < ... < +inf < NaN). bf16 widens to f32
+    exactly; no key equals int32's minimum except that of the one f32 NaN
+    whose bits are all ones."""
+    bits = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.int32)
+    return jnp.where(bits < 0, bits ^ 0x7FFFFFFF, bits)
+
+
+def top_k_indices(x, k: int):
+    """``jax.lax.top_k(x, k)[1]`` by k max-reductions over the last axis.
+
+    Higher value first; among equal values the lower index first. Pass j
+    takes the first argmax of the keys with passes 0..j-1's indices masked
+    below every key, so the picks stay distinct even in a row of -inf.
+    TPU lowers ``top_k`` to a sort of the whole row; this reads the row k
+    times.
+    """
+    key = _total_order_key(x)
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    floor = jnp.iinfo(jnp.int32).min
+    picks = []
+    for _ in range(k):
+        i = jnp.argmax(key, axis=-1).astype(jnp.int32)
+        picks.append(i)
+        key = jnp.where(iota == i[..., None], floor, key)
+    return jnp.stack(picks, axis=-1)
+
+
 def expand_tree(verify_fn, draft_cfg: ModelConfig, draft_caches, topo: TreeTopology,
                 pending_token, temperature: float = 0.0):
     """Fill the tree's token ids by expanding with the draft model.
@@ -99,13 +131,18 @@ def expand_tree(verify_fn, draft_cfg: ModelConfig, draft_caches, topo: TreeTopol
         if d == maxd:
             break
         # assign depth-(d+1) tokens: child i gets the rank[i]-th top token of
-        # its parent's draft logits
+        # its parent's draft logits, read from the depth-d parent rows only
         level = np.where(depths == d + 1)[0]
-        kmax = int(rank[level].max()) + 1 if len(level) else 1
+        kmax = int(rank[level].max()) + 1
+        rows, row_of = np.unique(topo.parents[level], return_inverse=True)
+        lo = int(rows[0])
+        # bfs parents are contiguous: a slice the reductions read in place
+        sel = (slice(lo, lo + len(rows)) if rows[-1] - lo + 1 == len(rows)
+               else rows)
         with jax.named_scope("ssv.draft.topk"):
-            _, topk_idx = jax.lax.top_k(logits, kmax)                # (B, T, kmax)
-        par = jnp.asarray(topo.parents[level])
-        rk = jnp.asarray(rank[level])
-        picked = topk_idx[:, par, rk]                                # (B, |level|)
+            topk_idx = top_k_indices(logits[:, sel], kmax)           # (B, R, kmax)
+        obs.count("draft.topk.rows", len(rows))
+        obs.count("draft.topk.passes", kmax)
+        picked = topk_idx[:, jnp.asarray(row_of), jnp.asarray(rank[level])]
         tokens = tokens.at[:, jnp.asarray(level)].set(picked)
     return tokens, node_q, updates
